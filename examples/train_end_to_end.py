@@ -35,10 +35,7 @@ def main() -> None:
         "workdir": "ckpts:runs/e2e", "lr": 1e-2,
     }
     if args.full:
-        payload["config_overrides"] = {}  # real CONFIG is selected by the
-        # jaxlocal trainer via get_smoke_config; --full documents intent:
-        # on TPU pods the bridge submits repro.launch.train with the full
-        # config — this container trains the reduced one end-to-end.
+        payload["preset"] = "published"  # xlstm-125m at its published widths
 
     with BridgeEnvironment(default_duration=0.05) as env:
         spec = env.make_spec("jaxlocal", script=json.dumps(payload),

@@ -1,65 +1,48 @@
-"""repro.compat — the version-portable JAX substrate layer.
+"""repro.compat — the one place this tree touches JAX's less stable APIs.
 
-The paper's Bridge Operator is agnostic to the external resource behind
-it (§5.1); this package makes the compute substrate equally agnostic to
-the installed JAX.  It is the SINGLE allowed entry point for every
-version-sensitive JAX API in this tree:
+The tree runs on one installed JAX (see ROADMAP.md "Supported JAX
+versions").  Every use of an API that has moved or been renamed across
+JAX releases goes through this package, so that the next move is absorbed
+here and call sites stay as they are:
 
-  * :func:`shard_map`        — ``jax.shard_map`` vs
-    ``jax.experimental.shard_map.shard_map``; ``check_vma`` vs
-    ``check_rep`` kwarg;
-  * :func:`use_mesh`         — ``jax.sharding.set_mesh`` vs
-    ``jax.sharding.use_mesh`` vs the ``with mesh:`` context;
-  * :func:`mosaic_params`    — ``pltpu.CompilerParams`` vs
-    ``pltpu.TPUCompilerParams`` vs omitting compiler params entirely;
-  * :func:`jit_sharded`      — ``jax.jit`` over PartitionSpec pytrees
-    (new JAX takes raw specs under a current mesh; old JAX needs them
-    bound to ``NamedSharding`` first);
-  * capability probes        — :func:`has_tpu`, :func:`pallas_available`,
-    :func:`pallas_interpret_default`, :func:`resolve_interpret`,
-    :func:`best_kernel_path` — so kernels pick pallas-TPU,
-    pallas-interpret, or the pure-XLA reference path at runtime.
+  * :func:`shard_map`        — ``jax.shard_map``;
+  * :func:`use_mesh`         — ``jax.sharding.set_mesh``;
+  * :func:`mosaic_params`    — ``pltpu.CompilerParams`` for ``pallas_call``;
+  * :func:`jit_sharded`      — ``jax.jit`` over PartitionSpec pytrees,
+    bound to ``NamedSharding``s on the given mesh;
+  * :func:`resolve_interpret` — Mosaic on an accelerator, the Pallas
+    interpreter on the CPU backend (:mod:`repro.compat.capability`).
 
-Rules of the seam (enforced by tests/test_compat.py's source scan):
-  1. no module under ``src/repro/`` outside this package may reference
-     ``jax.shard_map``, ``set_mesh``, or ``*CompilerParams`` directly;
-  2. resolution is by API probing, never by version-string comparison;
-  3. when the JAX pin moves and an API churns again, absorb it HERE —
-     call sites must not grow version checks.
+Rule of the seam (enforced by tests/test_compat.py's source scan): no
+module under ``src/repro/`` outside this package may reference
+``jax.shard_map``, ``set_mesh`` or ``*CompilerParams`` directly.
 """
 from __future__ import annotations
 
 from typing import Any, Dict
 
-from repro.compat.capability import (best_kernel_path, has_tpu,
-                                     pallas_available,
-                                     pallas_interpret_default,
+import jax
+
+from repro.compat.capability import (kernel_path, pallas_interpret_default,
                                      resolve_interpret)
 from repro.compat.jitting import (cost_analysis_dict, jit_sharded,
                                   resolve_shardings)
-from repro.compat.meshctx import use_mesh, use_mesh_source
-from repro.compat.pallas import compiler_params_source, mosaic_params
-from repro.compat.shard import shard_map, shard_map_source
-from repro.compat.versions import at_least, jax_version, jax_version_tuple
+from repro.compat.meshctx import use_mesh
+from repro.compat.pallas import mosaic_params
+from repro.compat.shard import shard_map
 
 __all__ = [
-    "at_least", "best_kernel_path", "compiler_params_source",
-    "cost_analysis_dict", "describe",
-    "has_tpu", "jax_version", "jax_version_tuple", "jit_sharded",
-    "mosaic_params", "pallas_available", "pallas_interpret_default",
-    "resolve_interpret", "resolve_shardings", "shard_map",
-    "shard_map_source", "use_mesh", "use_mesh_source",
+    "cost_analysis_dict", "describe", "jit_sharded", "kernel_path",
+    "mosaic_params", "pallas_interpret_default", "resolve_interpret",
+    "resolve_shardings", "shard_map", "use_mesh",
 ]
 
 
 def describe() -> Dict[str, Any]:
-    """How every seam resolved on this JAX — for logs and bug reports."""
+    """The installed JAX, its default backend and the kernel path — for
+    logs and bug reports."""
     return {
-        "jax_version": jax_version(),
-        "shard_map": shard_map_source(),
-        "use_mesh": use_mesh_source(),
-        "compiler_params": compiler_params_source(),
-        "pallas_available": pallas_available(),
-        "has_tpu": has_tpu(),
-        "best_kernel_path": best_kernel_path(),
+        "jax_version": jax.__version__,
+        "backend": jax.default_backend(),
+        "kernel_path": kernel_path(),
     }

@@ -1,42 +1,20 @@
-"""Runtime capability probes: which kernel path can this process run?
-
-Three tiers, best first:
-  * pallas-TPU       — a TPU is attached; ``pallas_call`` lowers via Mosaic;
-  * pallas-interpret — no TPU, but Pallas imports; kernel bodies run in
-    Python on CPU (bit-accurate correctness path for tests/containers);
-  * xla              — Pallas itself is unavailable; callers fall back to
-    the pure-jnp reference implementations.
+"""Which Pallas path this process runs: Mosaic, or the interpreter.
 
 ``interpret=None`` in the kernel wrappers means "pick for me":
-:func:`resolve_interpret` maps it to ``not has_tpu()`` so the same call
-site compiles on a pod and interprets in a CPU container.  Set
-``REPRO_PALLAS_INTERPRET=0/1`` to force either mode.
+:func:`resolve_interpret` chooses interpret mode exactly when JAX's default
+backend is the CPU, so the same call site compiles through Mosaic on a TPU
+and interprets in a CPU container.  The backend comes from JAX itself: a
+device query that fails raises, and is never read as "no accelerator".
+
+``REPRO_PALLAS_INTERPRET=0/1`` forces either mode.  The compile tests set it
+to 0 to lower the model's kernels for a described TPU from a CPU process.
 """
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional
 
-
-@functools.lru_cache(maxsize=None)
-def has_tpu() -> bool:
-    import jax
-
-    try:
-        return len(jax.devices("tpu")) > 0
-    except RuntimeError:
-        return False
-
-
-@functools.lru_cache(maxsize=None)
-def pallas_available() -> bool:
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except ImportError:
-        return False
-    return True
+import jax
 
 
 def pallas_interpret_default() -> bool:
@@ -44,7 +22,7 @@ def pallas_interpret_default() -> bool:
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None:
         return env not in ("0", "false", "False", "")
-    return not has_tpu()
+    return jax.default_backend() == "cpu"
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -54,8 +32,6 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return bool(interpret)
 
 
-def best_kernel_path() -> str:
-    """'pallas_tpu' | 'pallas_interpret' | 'xla' for this process."""
-    if not pallas_available():
-        return "xla"
-    return "pallas_tpu" if has_tpu() else "pallas_interpret"
+def kernel_path() -> str:
+    """'pallas_interpret' | 'mosaic': what ``interpret=None`` runs here."""
+    return "pallas_interpret" if pallas_interpret_default() else "mosaic"

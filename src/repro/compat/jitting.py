@@ -1,14 +1,10 @@
-"""Version-portable sharded ``jax.jit``.
+"""Sharded ``jax.jit`` over PartitionSpec pytrees.
 
 The step bundles (repro.steps) carry *PartitionSpec pytrees* as their
-in/out shardings.  Newer JAX accepts raw specs in ``jax.jit`` whenever a
-mesh has been made current (``set_mesh``); 0.4.x rejects them with
-"jax.jit only supports `Sharding`s being passed to in_shardings".
-
-:func:`resolve_shardings` closes the gap by binding every spec leaf to a
-concrete ``NamedSharding`` on the given mesh — valid on every JAX
-version — and :func:`jit_sharded` is the drop-in ``jax.jit`` wrapper the
-launchers use.  ``None`` subtrees (= let XLA decide) pass through
+in/out shardings.  :func:`resolve_shardings` binds every spec leaf to a
+concrete ``NamedSharding`` on the given mesh, so the step does not depend
+on which mesh is current, and :func:`jit_sharded` is the ``jax.jit``
+wrapper the launchers use.  ``None`` subtrees (= let XLA decide) pass through
 untouched, as do leaves that are already ``Sharding`` objects.
 """
 from __future__ import annotations
@@ -48,12 +44,5 @@ def jit_sharded(fn: Callable[..., Any], mesh: Mesh, *,
 
 
 def cost_analysis_dict(compiled: Any) -> dict:
-    """``Compiled.cost_analysis()`` normalized to one flat dict.
-
-    0.4.x returns a list with one per-executable dict; newer JAX returns
-    the dict itself (and may return None when analysis is unavailable).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()``, or ``{}`` where the backend gives none."""
+    return compiled.cost_analysis() or {}

@@ -1,45 +1,19 @@
-"""Version-portable "make this mesh current" context manager.
+"""The "make this mesh current" context manager.
 
-API churn absorbed here (newest first):
-  * ``jax.sharding.set_mesh(mesh)``   — jax >= 0.6 context manager;
-  * ``jax.sharding.use_mesh(mesh)``   — the 0.5.x experimental spelling;
-  * ``with mesh:``                    — the classic ``Mesh.__enter__``
-    global-mesh context, which is what 0.4.x provides.
-
-All three establish the mesh for subsequent ``jax.jit`` calls whose
-shardings name its axes; call sites always write
-``with use_mesh(mesh): ...`` and never touch the underlying API.
+Call sites write ``with use_mesh(mesh): ...``; it establishes the mesh for
+subsequent ``jax.jit`` calls whose shardings name its axes, through
+``jax.sharding.set_mesh``.
 """
 from __future__ import annotations
 
 import contextlib
-import functools
-from typing import Callable, Iterator
+from typing import Iterator
 
 import jax
 from jax.sharding import Mesh
 
 
-@functools.lru_cache(maxsize=None)
-def _resolve() -> Callable[[Mesh], object]:
-    for name in ("set_mesh", "use_mesh"):
-        fn = getattr(jax.sharding, name, None)
-        if fn is not None:
-            return fn
-    return lambda mesh: mesh  # Mesh is itself a context manager
-
-
-@functools.lru_cache(maxsize=None)
-def use_mesh_source() -> str:
-    fn = _resolve()
-    name = getattr(fn, "__name__", "")
-    if name in ("set_mesh", "use_mesh"):
-        return f"jax.sharding.{name}"
-    return "jax.sharding.Mesh.__enter__"
-
-
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh) -> Iterator[Mesh]:
-    """``with use_mesh(mesh):`` — portable across every supported JAX."""
-    with _resolve()(mesh):
+    with jax.sharding.set_mesh(mesh):
         yield mesh

@@ -1,13 +1,4 @@
-"""Version-portable Pallas-TPU compiler parameters.
-
-API churn absorbed here:
-  * class rename: ``pltpu.CompilerParams`` (new) vs
-    ``pltpu.TPUCompilerParams`` (old);
-  * field drift: unknown fields are filtered against the resolved
-    class so a renamed/removed knob degrades to "unset" instead of a
-    ``TypeError`` at kernel-build time;
-  * absence: if neither class exists (ancient/exotic builds) the
-    kernels simply run without Mosaic params.
+"""Pallas-TPU compiler parameters.
 
 Kernels splat the result into ``pl.pallas_call``::
 
@@ -16,54 +7,11 @@ Kernels splat the result into ``pl.pallas_call``::
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
-import inspect
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
-
-@functools.lru_cache(maxsize=None)
-def _params_cls():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:
-        return None
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    return None
-
-
-@functools.lru_cache(maxsize=None)
-def _accepted_fields() -> frozenset:
-    cls = _params_cls()
-    if cls is None:
-        return frozenset()
-    if dataclasses.is_dataclass(cls):
-        return frozenset(f.name for f in dataclasses.fields(cls))
-    try:
-        return frozenset(inspect.signature(cls).parameters)
-    except (TypeError, ValueError):
-        return frozenset()
-
-
-def compiler_params_source() -> Optional[str]:
-    cls = _params_cls()
-    return None if cls is None else f"pltpu.{cls.__name__}"
+from jax.experimental.pallas import tpu as pltpu
 
 
 def mosaic_params(**fields: Any) -> Dict[str, Any]:
-    """Build the ``compiler_params=`` kwarg dict for ``pl.pallas_call``.
-
-    Returns ``{"compiler_params": <params obj>}`` on JAX versions that
-    support it, ``{}`` otherwise — callers ``**``-splat either way.
-    Fields the resolved class doesn't know are dropped (best-effort
-    tuning hints, not correctness knobs).
-    """
-    cls = _params_cls()
-    if cls is None:
-        return {}
-    accepted = _accepted_fields()
-    kept = {k: v for k, v in fields.items() if k in accepted}
-    return {"compiler_params": cls(**kept)}
+    """The ``compiler_params=`` kwarg dict for ``pl.pallas_call``."""
+    return {"compiler_params": pltpu.CompilerParams(**fields)}

@@ -1,57 +1,20 @@
-"""Version-portable ``shard_map``.
+"""``shard_map``.
 
-API churn absorbed here:
-  * location: ``jax.shard_map`` (jax >= 0.6) vs
-    ``jax.experimental.shard_map.shard_map`` (<= 0.5.x);
-  * the replication-check kwarg rename: ``check_vma`` (new) vs
-    ``check_rep`` (old) — callers always say ``check_vma`` and we
-    translate to whatever the resolved function accepts.
-
-Every ``shard_map`` call site in the tree MUST go through
-:func:`shard_map` below; a regression test scans for direct uses.
+Every ``shard_map`` call site in the tree goes through :func:`shard_map`
+below; a regression test scans for direct uses.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Optional
 
 import jax
-
-
-@functools.lru_cache(maxsize=None)
-def _resolve() -> Tuple[Callable[..., Any], frozenset]:
-    """Return (the real shard_map, the kwarg names it accepts)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    try:
-        accepted = frozenset(inspect.signature(fn).parameters)
-    except (TypeError, ValueError):  # builtins without a signature
-        accepted = frozenset({"check_rep", "check_vma", "auto"})
-    return fn, accepted
 
 
 def shard_map(f: Callable[..., Any], mesh, in_specs, out_specs, *,
               check_vma: Optional[bool] = None, **kwargs: Any
               ) -> Callable[..., Any]:
-    """Portable ``shard_map(f, mesh, in_specs, out_specs, ...)``.
-
-    ``check_vma`` follows the newest spelling; on older JAX it is passed
-    as ``check_rep``.  Unknown extra kwargs are forwarded verbatim so new
-    features keep working when the pin moves forward.
-    """
-    fn, accepted = _resolve()
+    """``jax.shard_map(f, mesh=..., in_specs=..., out_specs=..., ...)``."""
     if check_vma is not None:
-        if "check_vma" in accepted:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in accepted:
-            kwargs["check_rep"] = check_vma
-        # neither spelling: the check is gone upstream; drop silently
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def shard_map_source() -> str:
-    """Where shard_map resolved from (for describe()/diagnostics)."""
-    fn, _ = _resolve()
-    return f"{fn.__module__}.{fn.__qualname__}"
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

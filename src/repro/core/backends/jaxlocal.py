@@ -12,19 +12,29 @@ Job script = JSON::
      "checkpoint_every": 20, "workdir": "ckpts:runs/demo", "lr": 3e-3,
      "task": "affine", "crash_at_step": 0}
 
+``preset`` picks the model: ``"smoke"`` (the default) builds the arch's
+reduced same-family config, ``"published"`` its published widths; either
+way ``config_overrides`` is applied on top (e.g. ``{"n_layers": 4}`` to cut
+depth, ``{"attention_impl": "pallas"}`` for the Mosaic kernels).
+
 ``crash_at_step`` > 0 makes the job fail at that step (fault-injection for
 tests): a resubmitted job with the same workdir resumes from the last
 checkpoint rather than step 0.
+
+Each cluster owns a :class:`DevicePool`: a job runs on the least-used local
+device, so replicas of a service spread one per chip on a multi-chip host.
+With one device every job shares it.
 
 The REST dialect is slurmrestd (this is "our SLURM": same API, real work),
 so the generic controller drives it with the plain SlurmAdapter.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
 
@@ -41,6 +51,70 @@ class JaxLocalAdapter(SlurmAdapter):
     capabilities = SlurmAdapter.capabilities
 
 
+PRESETS = ("smoke", "published")
+
+
+def job_config(spec: Dict[str, Any]):
+    """The ``ModelConfig`` a job script asks for (see the module docstring)."""
+    from repro.configs.base import get_config, get_smoke_config
+
+    preset = spec.get("preset", "smoke")
+    if preset not in PRESETS:
+        raise ValueError(f"unknown preset {preset!r}; known: {PRESETS}")
+    make = get_smoke_config if preset == "smoke" else get_config
+    return make(spec.get("arch", "gemma-2b"),
+                **dict(spec.get("config_overrides", {})))
+
+
+class DevicePool:
+    """Hands each job the least-used local device for its lifetime.
+
+    Devices are listed lazily, at the first lease, so building a cluster
+    never touches JAX's backend."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._devices: Optional[list] = None
+        self.leases: Dict[str, Any] = {}  # job id -> device
+
+    @contextlib.contextmanager
+    def lease(self, job_id: str) -> Iterator[Any]:
+        import jax
+
+        with self._lock:
+            if self._devices is None:
+                self._devices = jax.local_devices()
+            held = list(self.leases.values())
+            dev = min(self._devices, key=held.count)
+            self.leases[job_id] = dev
+        try:
+            with jax.default_device(dev):
+                yield dev
+        finally:
+            with self._lock:
+                self.leases.pop(job_id, None)
+
+
+def make_train_step(cfg, opt_cfg):
+    """The jitted train step of ``train_job``: (params, opt_state, batch) ->
+    (params, opt_state, metrics), updating params and optimizer state in
+    place (both are donated)."""
+    import jax
+
+    from repro.models.transformer import forward_train
+    from repro.optim import adamw_update
+
+    def step_fn(params, opt_state, batch):
+        def loss_fn(p):
+            return forward_train(p, cfg, batch, remat=False)
+
+        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        new_p, new_o, om = adamw_update(grads, opt_state, params, opt_cfg)
+        return new_p, new_o, dict(metrics, **om)
+
+    return jax.jit(step_fn, donate_argnums=(0, 1))
+
+
 def train_job(spec: Dict[str, Any], store: ObjectStore,
               cancel: Optional[threading.Event] = None,
               log: Optional[list] = None) -> Dict[str, Any]:
@@ -48,26 +122,21 @@ def train_job(spec: Dict[str, Any], store: ObjectStore,
 
     Importable directly (examples/tests) or via the cluster payload below.
     """
-    import jax
     import jax.numpy as jnp
 
     from repro.checkpoint.manager import CheckpointManager
-    from repro.configs.base import ShapeConfig, get_smoke_config
     from repro.data.pipeline import DataConfig, SyntheticDataset
-    from repro.models.transformer import forward_train
-    from repro.optim import AdamWConfig, adamw_init, adamw_update
+    from repro.optim import AdamWConfig, adamw_init
     from repro.steps import init_model
 
-    arch = spec.get("arch", "gemma-2b")
     steps = int(spec.get("steps", 50))
     batch_sz = int(spec.get("batch", 4))
     seq = int(spec.get("seq", 32))
     ckpt_every = int(spec.get("checkpoint_every", 0))
     lr = float(spec.get("lr", 1e-3))
     crash_at = int(spec.get("crash_at_step", 0))
-    overrides = dict(spec.get("config_overrides", {}))
 
-    cfg = get_smoke_config(arch, **overrides)
+    cfg = job_config(spec)
     ds = SyntheticDataset(DataConfig(vocab=cfg.vocab, seq_len=seq,
                                      global_batch=batch_sz,
                                      task=spec.get("task", "affine"),
@@ -89,15 +158,7 @@ def train_job(spec: Dict[str, Any], store: ObjectStore,
             start_step, tree, _extra = resumed
             params, opt_state = tree["params"], tree["opt"]
 
-    @jax.jit
-    def step_fn(params, opt_state, batch):
-        def loss_fn(p):
-            return forward_train(p, cfg, batch, remat=False)
-
-        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        new_p, new_o, om = adamw_update(grads, opt_state, params, opt_cfg)
-        return new_p, new_o, dict(metrics, **om)
-
+    step_fn = make_train_step(cfg, opt_cfg)
     history = []
     for step in range(start_step, steps):
         if cancel is not None and cancel.is_set():
@@ -137,20 +198,37 @@ def serve_job(spec: Dict[str, Any], job: B.ClusterJob,
     (HTTP 500), which the service router treats as a replica fault and
     retries elsewhere — accepted requests are never silently dropped.
     Serve jobs NEVER auto-complete: only a cancel ends them.
+
+    The handler is installed (and the replica turns ready) only once the
+    engine's programs are compiled; ``engine.json`` among the job's outputs
+    then records the device, the set-up and compile seconds, and which
+    programs contain a Mosaic kernel (``tpu_custom_call``).
     """
-    from repro.configs.base import get_smoke_config
+    import jax
+
     from repro.serving.engine import ServingEngine
     from repro.steps import init_model
 
     arch = spec.get("arch", "gemma-2b")
     max_len = int(spec.get("max_len", 64))
     prefill_len = int(spec.get("prefill_len", 16))
-    cfg = get_smoke_config(arch, **dict(spec.get("config_overrides", {})))
+    cfg = job_config(spec)
+    t0 = time.perf_counter()
     _, params = init_model(cfg, seed=int(spec.get("seed", 0)),
                            max_seq=max_len)
+    params = jax.block_until_ready(params)
+    init_s = time.perf_counter() - t0
     eng = ServingEngine(cfg, params,
                         max_batch=int(spec.get("max_batch", 4)),
                         max_len=max_len, prefill_len=prefill_len)
+    device = jax.tree_util.tree_leaves(params)[0].devices().pop()
+    job.outputs["engine.json"] = json.dumps({
+        "model": cfg.name, "device": device.id,
+        "device_kind": device.device_kind, "init_s": init_s,
+        "compile_s": eng.compile_s,
+        "mosaic": {name: "tpu_custom_call" in prog.as_text()
+                   for name, prog in eng.programs.items()},
+    }).encode()
     cond = threading.Condition()
     results: Dict[int, Any] = {}
 
@@ -169,7 +247,8 @@ def serve_job(spec: Dict[str, Any], job: B.ClusterJob,
                     raise RuntimeError("replica cancelled mid-request")
                 cond.wait(timeout=0.05)
             req = results.pop(rid)
-        return {"tokens": req.generated, "served_by": job.id, "arch": arch}
+        return {"tokens": req.generated, "served_by": job.id, "arch": arch,
+                "device": device.id}
 
     job.handler = handler
     try:
@@ -192,12 +271,13 @@ def serve_job(spec: Dict[str, Any], job: B.ClusterJob,
             cond.notify_all()  # release parked handlers to see the cancel
 
 
-def jax_train_payload(store: ObjectStore) -> B.Payload:
+def jax_train_payload(store: ObjectStore, devices: DevicePool) -> B.Payload:
     def run(job: B.ClusterJob, cluster: B.SimulatedCluster) -> int:
         spec = json.loads(job.script)
-        if spec.get("mode") == "serve":
-            return serve_job(spec, job, cluster)
-        result = train_job(spec, store, cancel=job._cancel)
+        with devices.lease(job.id):
+            if spec.get("mode") == "serve":
+                return serve_job(spec, job, cluster)
+            result = train_job(spec, store, cancel=job._cancel)
         job.outputs[job.properties.get("OutputFileName", "train.out")] = (
             json.dumps({k: v for k, v in result.items() if k != "history"})
             .encode())
@@ -218,9 +298,12 @@ def make_jaxlocal_cluster(store: ObjectStore, name: str = "jaxlocal",
                           start_numbering: int = 7000) -> B.SimulatedCluster:
     # start_numbering is per-cluster so a second jaxlocal resource (serving
     # across managers) hands out non-overlapping job ids
-    return B.SimulatedCluster(name=name, slots=slots,
-                              payload=jax_train_payload(store),
-                              start_numbering=start_numbering)
+    devices = DevicePool()
+    cluster = B.SimulatedCluster(name=name, slots=slots,
+                                 payload=jax_train_payload(store, devices),
+                                 start_numbering=start_numbering)
+    cluster.devices = devices
+    return cluster
 
 
 def make_server(cluster: B.SimulatedCluster, token: str = "",

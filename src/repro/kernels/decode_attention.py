@@ -7,8 +7,9 @@ kv group share each streamed tile (GQA's arithmetic-intensity win, expressed
 as a (G x block_m) score tile that keeps the MXU busy instead of a
 vector-only dot).
 
-Grid: (B, Hkv, nm), nm innermost/sequential.  Valid-length masking reads a
-scalar per batch row from SMEM (scalar prefetch idiom).
+Grid: (B, Hkv, nm), nm innermost/sequential.  The (B,) valid lengths are a
+scalar-prefetch operand: the whole array sits in SMEM and each grid step
+reads its batch row's length from it.
 """
 from __future__ import annotations
 
@@ -42,7 +43,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale  # (G,bm)
 
-    valid = len_ref[0]
+    valid = len_ref[pl.program_id(0)]
     cols = mi * block_m + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(cols < valid, s, NEG_INF)
 
@@ -87,21 +88,25 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
 
     out = pl.pallas_call(
         kernel,
-        grid=(b, hkv, n_m),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b_, h, mi: (b_,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, group, d), lambda b_, h, mi: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_m, d), lambda b_, h, mi: (b_, h, mi, 0)),
-            pl.BlockSpec((1, 1, block_m, d), lambda b_, h, mi: (b_, h, mi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d), lambda b_, h, mi: (b_, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, n_m),
+            in_specs=[
+                pl.BlockSpec((1, 1, group, d),
+                             lambda b_, h, mi, lens: (b_, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_m, d),
+                             lambda b_, h, mi, lens: (b_, h, mi, 0)),
+                pl.BlockSpec((1, 1, block_m, d),
+                             lambda b_, h, mi, lens: (b_, h, mi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, group, d),
+                                   lambda b_, h, mi, lens: (b_, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((group, LANES), jnp.float32),
+                pltpu.VMEM((group, LANES), jnp.float32),
+                pltpu.VMEM((group, d), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
         interpret=interpret,
         **mosaic_params(dimension_semantics=("parallel", "parallel",
                                              "arbitrary")),

@@ -5,11 +5,8 @@ Layout contract with the model code (repro.models.layers): activations are
 (B, H, S, D).  Wrappers transpose, pad sequences to block multiples, call
 the kernel, and slice back.
 
-Substrate dispatch (via repro.compat): ``interpret=None`` (the default)
-auto-selects — Mosaic lowering on TPU, Python interpret mode on CPU; and
-when Pallas itself cannot be imported on the installed JAX, each wrapper
-degrades to the pure-XLA reference implementation in ``repro.kernels.ref``
-so the model code never sees the substrate change.
+``interpret=None`` (the default) auto-selects through repro.compat: Mosaic
+lowering on an accelerator, Python interpret mode on the CPU backend.
 """
 from __future__ import annotations
 
@@ -19,13 +16,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pallas_available, resolve_interpret
-from repro.kernels import ref as _ref
-
-if pallas_available():
-    from repro.kernels.decode_attention import decode_attention_bhd
-    from repro.kernels.flash_attention import flash_attention_bhsd
-    from repro.kernels.ssm_scan import ssm_scan_chunked
+from repro.compat import resolve_interpret
+from repro.kernels import ssm_scan as _ssm
+from repro.kernels.decode_attention import decode_attention_bhd
+from repro.kernels.flash_attention import flash_attention_bhsd
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -57,11 +51,6 @@ def _flash_attention(q, k, v, *, causal, block_q, block_k, interpret):
     if not causal:
         raise NotImplementedError("pallas path is causal-only; xla handles "
                                   "bidirectional encoders")
-    if not pallas_available():
-        out = _ref.flash_attention_ref(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2), causal=True)
-        return jnp.swapaxes(out, 1, 2)
     s = q.shape[1]
     qt = _pad_to(jnp.swapaxes(q, 1, 2), 2, block_q)
     kt = _pad_to(jnp.swapaxes(k, 1, 2), 2, block_k)
@@ -82,10 +71,6 @@ def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def _decode_attention(q, cache_k, cache_v, lengths, *, block_m, interpret):
-    if not pallas_available():
-        return _ref.decode_attention_ref(
-            q[:, 0], jnp.swapaxes(cache_k, 1, 2),
-            jnp.swapaxes(cache_v, 1, 2), lengths.astype(jnp.int32))[:, None]
     qb = q[:, 0]  # (B,Hq,D)
     kt = _pad_to(jnp.swapaxes(cache_k, 1, 2), 2, block_m)
     vt = _pad_to(jnp.swapaxes(cache_v, 1, 2), 2, block_m)
@@ -105,8 +90,6 @@ def ssm_scan(dA: jax.Array, dBx: jax.Array, C: jax.Array, *, chunk: int = 16,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _ssm_scan(dA, dBx, C, *, chunk, interpret):
-    if not pallas_available():
-        return _ref.ssm_scan_ref(dA, dBx, C)
     s = dA.shape[1]
     pad = (-s) % chunk
     if pad:
@@ -115,7 +98,8 @@ def _ssm_scan(dA, dBx, C, *, chunk, interpret):
                      constant_values=1.0)
         dBx = jnp.pad(dBx, ((0, 0), (0, pad), (0, 0), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
-    y, h_last = ssm_scan_chunked(dA, dBx, C, chunk=chunk, interpret=interpret)
+    y, h_last = _ssm.ssm_scan_chunked(dA, dBx, C, chunk=chunk,
+                                      interpret=interpret)
     return y[:, :s], h_last
 
 
@@ -130,11 +114,6 @@ def ssm_scan_fused(delta: jax.Array, B: jax.Array, C: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _ssm_scan_fused(delta, B, C, x, A, *, chunk, interpret):
-    if not pallas_available():
-        dA, dBx = _ref.ssm_discretize(delta, B, x, A)
-        return _ref.ssm_scan_ref(dA, dBx, C)
-    from repro.kernels.ssm_scan import ssm_scan_fused as _fused
-
     s = delta.shape[1]
     pad = (-s) % chunk
     if pad:
@@ -143,5 +122,6 @@ def _ssm_scan_fused(delta, B, C, x, A, *, chunk, interpret):
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-    y, h_last = _fused(delta, B, C, x, A, chunk=chunk, interpret=interpret)
+    y, h_last = _ssm.ssm_scan_fused(delta, B, C, x, A, chunk=chunk,
+                                    interpret=interpret)
     return y[:, :s], h_last

@@ -2,12 +2,20 @@
 
 The recurrence h_t = dA_t * h_{t-1} + dBx_t is memory-bound: the XLA
 associative-scan materializes all (B,S,di,N) intermediates in HBM
-(O(S log S) traffic).  The kernel streams (chunk, di, N) tiles through VMEM,
-carries h in scratch across the sequential chunk grid dim, and fuses the
-y_t = <h_t, C_t> contraction so h never round-trips to HBM — one read of
+(O(S log S) traffic).  The kernel streams (chunk, BLOCK_D, N) tiles through
+VMEM, carries h in scratch across the sequential chunk grid dim, and fuses
+the y_t = <h_t, C_t> contraction so h never round-trips to HBM — one read of
 dA/dBx/C and one write of y total.
 
-Grid: (B, n_chunks), chunks innermost/sequential.
+Grid: (B, di / BLOCK_D, n_chunks), chunks innermost/sequential.  The di
+channels are independent, so tiling them bounds VMEM and gives the
+parallel dims more work.
+
+Mosaic layout: the steps of a chunk are unrolled with static indices (the
+TPU lowering has no dynamic_slice on values).  Per-step column vectors
+(y_t, and delta_t / x_t in the fused variant) live on the LANE axis of
+channel-major (di, chunk) tiles, so every broadcast is along a native axis;
+the wrappers transpose those operands to and from (B, S, di).
 """
 from __future__ import annotations
 
@@ -21,72 +29,92 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import mosaic_params, resolve_interpret
 
+# di channels per grid step: (chunk, BLOCK_D, N) f32 tiles of dA/dBx, with N
+# padded to 128 lanes, stay a few MB of VMEM (the whole di would not fit)
+BLOCK_D = 128
 
-def _ssm_kernel(dA_ref, dBx_ref, C_ref, y_ref, h_last_ref, h_scr, *,
+
+def _ssm_kernel(dA_ref, dBx_ref, C_ref, yT_ref, h_last_ref, h_scr, *,
                 chunk: int, n_chunks: int):
-    ci = pl.program_id(1)
+    ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    dA = dA_ref[0].astype(jnp.float32)       # (chunk, di, N)
-    dBx = dBx_ref[0].astype(jnp.float32)
-    C = C_ref[0].astype(jnp.float32)         # (chunk, N)
-
-    def step(t, carry):
-        h, y = carry
-        h = dA[t] * h + dBx[t]               # (di, N)
-        y = y.at[t].set(h @ C[t])            # (di,)
-        return h, y
-
-    h0 = h_scr[...]
-    y0 = jnp.zeros((chunk, dA.shape[1]), jnp.float32)
-    h, y = jax.lax.fori_loop(0, chunk, step, (h0, y0))
+    h = h_scr[...]                                       # (bd, N)
+    for t in range(chunk):
+        h = (dA_ref[0, t].astype(jnp.float32) * h
+             + dBx_ref[0, t].astype(jnp.float32))
+        c_t = C_ref[0, pl.ds(t, 1), :].astype(jnp.float32)   # (1, N)
+        yT_ref[0, 0, :, pl.ds(t, 1)] = jnp.sum(h * c_t, axis=1, keepdims=True)
     h_scr[...] = h
-    y_ref[0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _finish():
-        h_last_ref[0] = h_scr[...]
+        h_last_ref[0] = h
 
 
-def _ssm_fused_kernel(delta_ref, b_ref, c_ref, x_ref, a_ref, y_ref,
+def _ssm_fused_kernel(deltaT_ref, b_ref, c_ref, xT_ref, a_ref, yT_ref,
                       h_last_ref, h_scr, *, chunk: int, n_chunks: int):
     """Fused-discretization variant: dA/dBx are built IN VMEM from
     (delta, B, x, A) — HBM reads drop from O(S·di·N) to O(S·(di+N)),
     ~(di·N)/(di+N) x less traffic (e.g. 32x for di=3200, N=16).
-    The math must stay in lockstep with ref.ssm_discretize (the XLA
-    fallback path in ops.py uses that definition)."""
-    ci = pl.program_id(1)
+    The math must stay in lockstep with ref.ssm_discretize."""
+    ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    delta = delta_ref[0].astype(jnp.float32)   # (chunk, di)
-    Bm = b_ref[0].astype(jnp.float32)          # (chunk, N)
-    Cm = c_ref[0].astype(jnp.float32)          # (chunk, N)
-    x = x_ref[0].astype(jnp.float32)           # (chunk, di)
-    A = a_ref[...].astype(jnp.float32)         # (di, N)
-
-    def step(t, carry):
-        h, y = carry
-        dA = jnp.exp(delta[t][:, None] * A)            # (di, N) in VMEM
-        dBx = delta[t][:, None] * Bm[t][None, :] * x[t][:, None]
-        h = dA * h + dBx
-        y = y.at[t].set(h @ Cm[t])
-        return h, y
-
-    h0 = h_scr[...]
-    y0 = jnp.zeros((chunk, delta.shape[1]), jnp.float32)
-    h, y = jax.lax.fori_loop(0, chunk, step, (h0, y0))
+    A = a_ref[...].astype(jnp.float32)                   # (bd, N)
+    h = h_scr[...]
+    for t in range(chunk):
+        d_t = deltaT_ref[0, 0, :, pl.ds(t, 1)].astype(jnp.float32)  # (bd, 1)
+        x_t = xT_ref[0, 0, :, pl.ds(t, 1)].astype(jnp.float32)      # (bd, 1)
+        b_t = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32)          # (1, N)
+        c_t = c_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        h = jnp.exp(d_t * A) * h + (d_t * x_t) * b_t
+        yT_ref[0, 0, :, pl.ds(t, 1)] = jnp.sum(h * c_t, axis=1, keepdims=True)
     h_scr[...] = h
-    y_ref[0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _finish():
-        h_last_ref[0] = h_scr[...]
+        h_last_ref[0] = h
+
+
+def _tiling(s: int, di: int, chunk: int):
+    chunk = min(chunk, s)
+    if s % chunk:
+        raise ValueError(f"seq {s} % chunk {chunk}")
+    bd = BLOCK_D if di % BLOCK_D == 0 else di
+    return chunk, s // chunk, bd, di // bd
+
+
+def _channel_major(a: jax.Array, n_chunks: int, chunk: int) -> jax.Array:
+    """(B, S, di) -> (B, n_chunks, di, chunk)."""
+    b, _, di = a.shape
+    return jnp.swapaxes(a.reshape(b, n_chunks, chunk, di), 2, 3)
+
+
+def _seq_major(aT: jax.Array) -> jax.Array:
+    """(B, n_chunks, di, chunk) -> (B, S, di)."""
+    b, nc, di, chunk = aT.shape
+    return jnp.swapaxes(aT, 2, 3).reshape(b, nc * chunk, di)
+
+
+def _out_specs(bd: int, chunk: int, n: int):
+    return [
+        pl.BlockSpec((1, 1, bd, chunk), lambda b_, di_, ci: (b_, ci, di_, 0)),
+        pl.BlockSpec((1, bd, n), lambda b_, di_, ci: (b_, di_, 0)),
+    ]
+
+
+def _out_shape(b: int, n_chunks: int, di: int, chunk: int, n: int):
+    return [
+        jax.ShapeDtypeStruct((b, n_chunks, di, chunk), jnp.float32),
+        jax.ShapeDtypeStruct((b, di, n), jnp.float32),
+    ]
 
 
 def ssm_scan_fused(delta: jax.Array, B: jax.Array, C: jax.Array,
@@ -97,34 +125,25 @@ def ssm_scan_fused(delta: jax.Array, B: jax.Array, C: jax.Array,
     interpret = resolve_interpret(interpret)
     b, s, di = delta.shape
     n = B.shape[-1]
-    chunk = min(chunk, s)
-    if s % chunk:
-        raise ValueError(f"seq {s} % chunk {chunk}")
-    n_chunks = s // chunk
+    chunk, n_chunks, bd, n_d = _tiling(s, di, chunk)
     kernel = functools.partial(_ssm_fused_kernel, chunk=chunk,
                                n_chunks=n_chunks)
-    return pl.pallas_call(
+    col = pl.BlockSpec((1, 1, bd, chunk), lambda b_, di_, ci: (b_, ci, di_, 0))
+    row = pl.BlockSpec((1, chunk, n), lambda b_, di_, ci: (b_, ci, 0))
+    yT, h_last = pl.pallas_call(
         kernel,
-        grid=(b, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, di), lambda b_, ci: (b_, ci, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b_, ci: (b_, ci, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b_, ci: (b_, ci, 0)),
-            pl.BlockSpec((1, chunk, di), lambda b_, ci: (b_, ci, 0)),
-            pl.BlockSpec((di, n), lambda b_, ci: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, di), lambda b_, ci: (b_, ci, 0)),
-            pl.BlockSpec((1, di, n), lambda b_, ci: (b_, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, di), jnp.float32),
-            jax.ShapeDtypeStruct((b, di, n), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((di, n), jnp.float32)],
+        grid=(b, n_d, n_chunks),
+        in_specs=[col, row, row, col,
+                  pl.BlockSpec((bd, n), lambda b_, di_, ci: (di_, 0))],
+        out_specs=_out_specs(bd, chunk, n),
+        out_shape=_out_shape(b, n_chunks, di, chunk, n),
+        scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         interpret=interpret,
-        **mosaic_params(dimension_semantics=("parallel", "arbitrary")),
-    )(delta, B, C, x, A)
+        **mosaic_params(dimension_semantics=("parallel", "parallel",
+                                             "arbitrary")),
+    )(_channel_major(delta, n_chunks, chunk), B, C,
+      _channel_major(x, n_chunks, chunk), A)
+    return _seq_major(yT), h_last
 
 
 def ssm_scan_chunked(dA: jax.Array, dBx: jax.Array, C: jax.Array, *,
@@ -133,29 +152,19 @@ def ssm_scan_chunked(dA: jax.Array, dBx: jax.Array, C: jax.Array, *,
     Returns (y (B,S,di) f32, h_last (B,di,N) f32)."""
     interpret = resolve_interpret(interpret)
     b, s, di, n = dA.shape
-    chunk = min(chunk, s)
-    if s % chunk:
-        raise ValueError(f"seq {s} % chunk {chunk}")
-    n_chunks = s // chunk
-
+    chunk, n_chunks, bd, n_d = _tiling(s, di, chunk)
     kernel = functools.partial(_ssm_kernel, chunk=chunk, n_chunks=n_chunks)
-    return pl.pallas_call(
+    tile = pl.BlockSpec((1, chunk, bd, n), lambda b_, di_, ci: (b_, ci, di_, 0))
+    yT, h_last = pl.pallas_call(
         kernel,
-        grid=(b, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, di, n), lambda b_, ci: (b_, ci, 0, 0)),
-            pl.BlockSpec((1, chunk, di, n), lambda b_, ci: (b_, ci, 0, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b_, ci: (b_, ci, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, di), lambda b_, ci: (b_, ci, 0)),
-            pl.BlockSpec((1, di, n), lambda b_, ci: (b_, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, di), jnp.float32),
-            jax.ShapeDtypeStruct((b, di, n), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((di, n), jnp.float32)],
+        grid=(b, n_d, n_chunks),
+        in_specs=[tile, tile,
+                  pl.BlockSpec((1, chunk, n), lambda b_, di_, ci: (b_, ci, 0))],
+        out_specs=_out_specs(bd, chunk, n),
+        out_shape=_out_shape(b, n_chunks, di, chunk, n),
+        scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         interpret=interpret,
-        **mosaic_params(dimension_semantics=("parallel", "arbitrary")),
+        **mosaic_params(dimension_semantics=("parallel", "parallel",
+                                             "arbitrary")),
     )(dA, dBx, C)
+    return _seq_major(yT), h_last

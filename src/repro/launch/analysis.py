@@ -12,7 +12,7 @@ Two collective-byte conventions are recorded:
                   (all-reduce 2(g-1)/g, all-gather/reduce-scatter (g-1)/g,
                   all-to-all (g-1)/g, collective-permute 1x).
 
-Hardware model: TPU v5e (see repro.launch.mesh.HW).
+Hardware model: the dry-run's target chip (repro.launch.mesh.peaks).
 """
 from __future__ import annotations
 
@@ -20,7 +20,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.launch.mesh import HW
+from repro.launch.mesh import DRYRUN_DEVICE_KIND, peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -122,10 +122,11 @@ def collective_stats(hlo_text: str) -> CollectiveStats:
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
                    coll: CollectiveStats) -> Dict[str, float]:
     """The three roofline terms in SECONDS (per step, per chip)."""
-    t_compute = flops_per_dev / HW["peak_flops_bf16"]
-    t_memory = bytes_per_dev / HW["hbm_bw"]
-    t_coll_operand = coll.total_operand / HW["ici_bw"]
-    t_coll_wire = coll.total_wire / HW["ici_bw"]
+    hw = peaks(DRYRUN_DEVICE_KIND)
+    t_compute = flops_per_dev / hw["peak_flops_bf16"]
+    t_memory = bytes_per_dev / hw["hbm_bw"]
+    t_coll_operand = coll.total_operand / hw["ici_bw"]
+    t_coll_wire = coll.total_wire / hw["ici_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll_operand, "collective_wire_s": t_coll_wire}
     dom = max(("compute_s", "memory_s", "collective_s"), key=lambda k: terms[k])

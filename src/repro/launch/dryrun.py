@@ -24,7 +24,7 @@ from repro.compat import cost_analysis_dict, jit_sharded, use_mesh
 from repro.configs.base import ARCH_IDS, SHAPES, cells, get_config
 from repro.launch.analysis import (collective_stats, memory_stats_dict,
                                    model_flops, roofline_terms)
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import DRYRUN_DEVICE_KIND, make_production_mesh, peaks
 from repro.steps import make_step
 
 # per-arch strategy: 2-D weight sharding where TP-only cannot fit HBM
@@ -197,7 +197,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         "model_flops_per_dev": mf / n_chips,
         "useful_flops_ratio": (mf / n_chips / acct["flops"])
         if acct["flops"] else None,
-        "hbm_fit": (mem.get("peak_bytes_per_device", 0) <= HW["hbm_bytes"])
+        "hbm_fit": (mem.get("peak_bytes_per_device", 0)
+                    <= peaks(DRYRUN_DEVICE_KIND)["hbm_bytes"])
         if mem else None,
         "n_params": cfg.n_params(),
         "n_active_params": cfg.n_active_params(),

@@ -21,10 +21,27 @@ def make_local_mesh(data: int = 1, model: int = 1):
     return jax.make_mesh((data, model), ("data", "model"))
 
 
-# TPU v5e hardware model used for the roofline analysis (per chip).
-HW = {
-    "peak_flops_bf16": 197e12,  # FLOP/s
-    "hbm_bw": 819e9,            # B/s
-    "ici_bw": 50e9,             # B/s per link
-    "hbm_bytes": 16 * 1024**3,  # capacity
+# Per-chip peaks, keyed by ``jax.Device.device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM bandwidth,
+# 16 GB HBM, 1,600 Gbit/s interconnect per chip (taken as 4 links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,  # FLOP/s
+        "hbm_bw": 819e9,            # B/s
+        "ici_bw": 50e9,             # B/s per link
+        "hbm_bytes": 16 * 1024**3,  # capacity
+    },
 }
+
+# the chip the dry-run matrix and its roofline model target
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak table of one chip; a device that is not in the table is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table for device_kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None

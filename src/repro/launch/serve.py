@@ -15,6 +15,7 @@ import time
 import numpy as np
 
 from repro.configs.base import ARCH_IDS, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ServingEngine
 from repro.steps import init_model
 
@@ -37,6 +38,7 @@ def main() -> None:
     p.add_argument("--json", action="store_true",
                    help="emit the summary as one JSON object")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     if cfg.family == "encdec":

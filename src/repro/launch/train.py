@@ -23,6 +23,7 @@ from repro.compat import jit_sharded, use_mesh
 from repro.configs.base import ARCH_IDS, ShapeConfig, get_config, get_smoke_config
 from repro.core.objectstore import ObjectStore
 from repro.data import DataConfig, SyntheticDataset, with_frontend_stubs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.params import init_params
 from repro.models.transformer import model_defs
@@ -48,6 +49,7 @@ def main() -> None:
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")

@@ -77,10 +77,14 @@ def attention_defs(cfg) -> Params:
     d, h = cfg.d_model, cfg.resolved_head_dim
     dt = adtype(cfg)
     return {
-        "wq": ParamDef((d, cfg.n_heads, h), ("embed", "heads", "head_dim"), dtype=dt),
-        "wk": ParamDef((d, cfg.n_kv_heads, h), ("embed", "kv_heads", "head_dim"), dtype=dt),
-        "wv": ParamDef((d, cfg.n_kv_heads, h), ("embed", "kv_heads", "head_dim"), dtype=dt),
-        "wo": ParamDef((cfg.n_heads, h, d), ("heads", "head_dim", "embed"), dtype=dt),
+        "wq": ParamDef((d, cfg.n_heads, h), ("embed", "heads", "head_dim"), dtype=dt,
+                       fan_in=d),
+        "wk": ParamDef((d, cfg.n_kv_heads, h), ("embed", "kv_heads", "head_dim"),
+                       dtype=dt, fan_in=d),
+        "wv": ParamDef((d, cfg.n_kv_heads, h), ("embed", "kv_heads", "head_dim"),
+                       dtype=dt, fan_in=d),
+        "wo": ParamDef((cfg.n_heads, h, d), ("heads", "head_dim", "embed"), dtype=dt,
+                       fan_in=cfg.n_heads * h),
     }
 
 

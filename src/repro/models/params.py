@@ -21,8 +21,11 @@ class ParamDef:
     shape: Tuple[int, ...]
     axes: Tuple[Optional[str], ...]  # logical axis name per dim (None = replicated)
     init: str = "normal"  # normal | zeros | ones | scaled | embed
-    scale: float = 1.0  # stddev multiplier / fan-in override
+    scale: float = 1.0  # stddev multiplier
     dtype: Any = jnp.bfloat16
+    # inputs summed per output element; 0 = the second-to-last dim (right
+    # for (in, out) matrices, wrong for head-split projections)
+    fan_in: int = 0
 
     def __post_init__(self) -> None:
         if len(self.shape) != len(self.axes):
@@ -39,10 +42,10 @@ def _init_leaf(rng: jax.Array, d: ParamDef) -> jax.Array:
     if d.init == "ones":
         return jnp.ones(d.shape, d.dtype)
     if d.init == "normal":
-        # truncated-normal, stddev = scale / sqrt(fan_in); fan_in = second-to-last
-        # dim for matrices (stacked-layer leading dims excluded by convention:
-        # the last two dims are the matmul dims).
-        fan_in = d.shape[-2] if len(d.shape) >= 2 else d.shape[-1]
+        # truncated-normal, stddev = scale / sqrt(fan_in); fan_in defaults to
+        # the second-to-last dim (stacked-layer leading dims excluded by
+        # convention: the last two dims are the matmul dims).
+        fan_in = d.fan_in or (d.shape[-2] if len(d.shape) >= 2 else d.shape[-1])
         std = d.scale / np.sqrt(max(fan_in, 1))
         x = jax.random.truncated_normal(rng, -2.0, 2.0, d.shape, jnp.float32) * std
         return x.astype(d.dtype)
@@ -55,10 +58,16 @@ def _init_leaf(rng: jax.Array, d: ParamDef) -> jax.Array:
     raise ValueError(f"unknown init {d.init!r}")
 
 
+# one program per leaf: the f32 draw, the scaling and the cast fuse, so the
+# device holds one leaf in its final dtype at a time instead of several f32
+# temporaries (a full-width layer stack is GBs)
+_init_leaf_jit = jax.jit(_init_leaf, static_argnums=1)
+
+
 def init_params(rng: jax.Array, defs: Any) -> Any:
     leaves, treedef = jax.tree_util.tree_flatten(defs, is_leaf=is_paramdef)
     rngs = jax.random.split(rng, len(leaves))
-    vals = [_init_leaf(r, d) for r, d in zip(rngs, leaves)]
+    vals = [_init_leaf_jit(r, d) for r, d in zip(rngs, leaves)]
     return jax.tree_util.tree_unflatten(treedef, vals)
 
 

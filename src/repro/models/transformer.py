@@ -18,6 +18,8 @@ Homogeneous stacks iterate with lax.scan over stacked per-layer params
 """
 from __future__ import annotations
 
+import dataclasses
+
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -36,7 +38,8 @@ Params = Dict[str, Any]
 
 def stack_defs(defs: Any, n: int) -> Any:
     return jax.tree_util.tree_map(
-        lambda d: ParamDef((n,) + d.shape, ("layers",) + d.axes, d.init, d.scale, d.dtype),
+        lambda d: dataclasses.replace(d, shape=(n,) + d.shape,
+                                      axes=("layers",) + d.axes),
         defs,
         is_leaf=is_paramdef,
     )
@@ -201,11 +204,13 @@ def cross_entropy(logits: jax.Array, targets: jax.Array, mask: jax.Array) -> jax
     return nll.sum() / jnp.maximum(mask.sum(), 1.0)
 
 
-def forward_train(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
-                  window: int = 0, remat: bool = True) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    if cfg.family == "encdec":
-        return _forward_train_encdec(params, cfg, batch, remat)
-    x, positions, tokens = _embed_inputs(params, cfg, batch)
+def forward_logits(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
+                   window: int = 0, remat: bool = False
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Decoder-only forward over whole sequences: (logits at every text
+    position, summed auxiliary loss).  No cache — the plain reference the
+    cached prefill/decode path is checked against."""
+    x, positions, _ = _embed_inputs(params, cfg, batch)
     if cfg.family == "ssm":
         x, aux_total = _run_xlstm(params, x, cfg)
     else:
@@ -216,7 +221,14 @@ def forward_train(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     x = L.apply_norm(params["ln_f"], x, cfg.norm)
     if cfg.family == "vlm":  # strip image positions before unembedding
         x = x[:, -batch["tokens"].shape[1]:]
-    logits = L.unembed(params["embed"], x, cfg)
+    return L.unembed(params["embed"], x, cfg), aux_total
+
+
+def forward_train(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
+                  window: int = 0, remat: bool = True) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    if cfg.family == "encdec":
+        return _forward_train_encdec(params, cfg, batch, remat)
+    logits, aux_total = forward_logits(params, cfg, batch, window, remat)
     loss = cross_entropy(logits, batch["targets"], batch["mask"])
     total = loss + 0.01 * aux_total
     return total, {"loss": loss, "aux": aux_total}

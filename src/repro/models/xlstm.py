@@ -213,5 +213,9 @@ def slstm_decode(p: Params, x: jax.Array, state: Dict[str, jax.Array], cfg
 
 def init_slstm_state(cfg, batch: int) -> Dict[str, jax.Array]:
     h, dh = cfg.n_heads, cfg.d_model // cfg.n_heads
-    z = jnp.zeros((batch, h, dh), jnp.float32)
-    return {"c": z, "n": z, "h": z, "m": jnp.full((batch, h, dh), -1e30, jnp.float32)}
+    # one buffer per leaf: the serving engine donates its cache, and a buffer
+    # that appears twice in a donated argument cannot be donated
+    def z():
+        return jnp.zeros((batch, h, dh), jnp.float32)
+    return {"c": z(), "n": z(), "h": z(),
+            "m": jnp.full((batch, h, dh), -1e30, jnp.float32)}

@@ -12,11 +12,18 @@ right-padded to ``prefill_len`` and masked via the cache's valid-length
 prompt token (idempotent KV write), which sidesteps the padded-last-position
 logits problem.  Recurrent families (ssm/hybrid) fold pads into their state,
 so the engine requires exact-length prompts for them.
+
+The three device programs (prefill, cache insert, decode) are compiled ahead
+of time when the engine is built, against the engine's fixed shapes; insert
+and decode donate the batched cache, so a tick holds one copy of it.
+``programs`` keeps the compiled executables for inspection (HLO text,
+``memory_analysis()``) and ``compile_s`` the time it took.
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -69,11 +76,11 @@ class ServingEngine:
             _batch_axis(jax.tree_util.keystr(p))
             for p, _ in jax.tree_util.tree_flatten_with_path(self.cache)[0]]
 
-        self._prefill = jax.jit(
-            lambda params, toks: DEC.prefill(params, cfg, {"tokens": toks},
-                                             max_len=max_len))
-        self._decode = jax.jit(
-            lambda params, cache, toks: DEC.decode_step(params, cfg, cache, toks))
+        def prefill(params, toks):
+            return DEC.prefill(params, cfg, {"tokens": toks}, max_len=max_len)
+
+        def decode(params, cache, toks):
+            return DEC.decode_step(params, cfg, cache, toks)
 
         def insert(cache, cache1, slot):
             flat, tdef = jax.tree_util.tree_flatten(cache)
@@ -86,7 +93,19 @@ class ServingEngine:
                     leaf.dtype), tuple(idx)))
             return jax.tree_util.tree_unflatten(tdef, out)
 
-        self._insert = jax.jit(insert)
+        toks1 = jax.ShapeDtypeStruct((1, prefill_len), jnp.int32)
+        cache1 = jax.eval_shape(prefill, params, toks1)[1]
+        t0 = time.perf_counter()
+        self.programs = {
+            "prefill": jax.jit(prefill).lower(params, toks1).compile(),
+            "insert": jax.jit(insert, donate_argnums=0).lower(
+                self.cache, cache1,
+                jax.ShapeDtypeStruct((), jnp.int32)).compile(),
+            "decode": jax.jit(decode, donate_argnums=1).lower(
+                params, self.cache,
+                jax.ShapeDtypeStruct((max_batch, 1), jnp.int32)).compile(),
+        }
+        self.compile_s = time.perf_counter() - t0
 
     # -- public ------------------------------------------------------------
 
@@ -128,7 +147,8 @@ class ServingEngine:
         plen = len(req.prompt)
         toks = np.zeros((1, self.prefill_len), np.int32)
         toks[0, :plen] = req.prompt
-        logits1, cache1 = self._prefill(self.params, jnp.asarray(toks))
+        logits1, cache1 = self.programs["prefill"](self.params,
+                                                   jnp.asarray(toks))
         if self.cfg.family in ("ssm", "hybrid"):
             # recurrent state is NOT idempotent: take the first token from
             # the prefill logits directly (prompts are exact-length here)
@@ -147,7 +167,8 @@ class ServingEngine:
             # token (idempotent kv write), yielding the first new-token logits
             cache1["pos"] = jnp.full((1,), plen - 1, jnp.int32)
             req._next_input = req.prompt[-1]  # type: ignore[attr-defined]
-        self.cache = self._insert(self.cache, cache1, slot)
+        self.cache = self.programs["insert"](self.cache, cache1,
+                                             np.int32(slot))
         self.slots[slot] = req
         self.stats["prefills"] += 1
 
@@ -156,8 +177,8 @@ class ServingEngine:
         for i, req in enumerate(self.slots):
             if req is not None:
                 toks[i, 0] = req._next_input  # type: ignore[attr-defined]
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          jnp.asarray(toks))
+        logits, self.cache = self.programs["decode"](self.params, self.cache,
+                                                     jnp.asarray(toks))
         nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         self.stats["decode_ticks"] += 1
         for i, req in enumerate(self.slots):
