@@ -42,6 +42,7 @@ from repro.core.backends import base as B
 from repro.core.backends.slurm import SlurmAdapter, make_server as make_slurm_server
 from repro.core.objectstore import ObjectStore
 from repro.core.rest import FaultProfile, RestServer
+from repro.tracing import span
 
 
 class JaxLocalAdapter(SlurmAdapter):
@@ -233,22 +234,26 @@ def serve_job(spec: Dict[str, Any], job: B.ClusterJob,
     results: Dict[int, Any] = {}
 
     def handler(body: Any) -> Dict[str, Any]:
-        body = body or {}
-        prompt = [int(t) for t in body.get("prompt", [])]
-        with cond:
-            if job._cancel.is_set():
-                raise RuntimeError("replica shutting down")
-            rid = eng.submit(prompt,
-                             max_new_tokens=int(body.get("max_new_tokens", 8)),
-                             eos_id=body.get("eos_id"))
-            cond.notify_all()
-            while rid not in results:
+        with span("replica.request"), contextlib.ExitStack() as enqueue:
+            body = body or {}
+            prompt = [int(t) for t in body.get("prompt", [])]
+            enqueue.enter_context(span("replica.enqueue"))
+            with cond:
                 if job._cancel.is_set():
-                    raise RuntimeError("replica cancelled mid-request")
-                cond.wait(timeout=0.05)
-            req = results.pop(rid)
-        return {"tokens": req.generated, "served_by": job.id, "arch": arch,
-                "device": device.id}
+                    raise RuntimeError("replica shutting down")
+                rid = eng.submit(
+                    prompt, max_new_tokens=int(body.get("max_new_tokens", 8)),
+                    eos_id=body.get("eos_id"))
+                cond.notify_all()
+                enqueue.close()  # ends replica.enqueue; the lock stays held
+                with span("replica.wait", rid=rid):
+                    while rid not in results:
+                        if job._cancel.is_set():
+                            raise RuntimeError("replica cancelled mid-request")
+                        cond.wait(timeout=0.05)
+                    req = results.pop(rid)
+            return {"tokens": req.generated, "served_by": job.id,
+                    "arch": arch, "device": device.id}
 
     job.handler = handler
     try:
@@ -257,13 +262,15 @@ def serve_job(spec: Dict[str, Any], job: B.ClusterJob,
                 busy = (bool(eng.pending)
                         or any(s is not None for s in eng.slots))
                 if not busy:
-                    cond.wait(timeout=0.02)
+                    with span("replica.idle"):
+                        cond.wait(timeout=0.02)
                     continue
-                eng.step()
-                if eng.finished:
-                    results.update(eng.finished)
-                    eng.finished.clear()
-                    cond.notify_all()
+                with span("replica.step"):
+                    eng.step()
+                    if eng.finished:
+                        results.update(eng.finished)
+                        eng.finished.clear()
+                        cond.notify_all()
         return -1
     finally:
         job.handler = None

@@ -38,6 +38,7 @@ from repro.core.backends import base as B
 from repro.core.resource import (BridgeService, BridgeServiceSpec,
                                  BridgeServiceStatus, ValidationError)
 from repro.core.rest import TransportError
+from repro.tracing import span
 
 
 class NoReadyReplicas(RuntimeError):
@@ -318,6 +319,10 @@ class ServiceEndpoint:
         request budget runs out; request-fault failures (4xx) raise
         immediately.  With no ready replica, the call parks and re-resolves
         until one appears or the budget is spent."""
+        with span("router.request"):
+            return self._request(payload, timeout)
+
+    def _request(self, payload: Any, timeout: Optional[float]) -> Any:
         deadline = time.time() + (timeout if timeout is not None
                                   else self.request_timeout)
         last_exc: Optional[Exception] = None

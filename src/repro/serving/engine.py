@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import decoding as DEC
+from repro.tracing import span
 
 Params = Dict[str, Any]
 
@@ -130,16 +131,22 @@ class ServingEngine:
     def step(self) -> bool:
         """One engine tick: admit into free slots, then decode.  Returns
         False when fully idle."""
-        admitted = False
-        for i, slot in enumerate(self.slots):
-            if slot is None and self.pending:
-                self._admit(i, self.pending.popleft())
-                admitted = True
-        active = [r for r in self.slots if r is not None]
-        if not active:
-            return admitted
-        self._decode_tick()
-        return True
+        with span("engine.step"):
+            admitted = False
+            for i, slot in enumerate(self.slots):
+                if slot is None and self.pending:
+                    req = self.pending.popleft()
+                    with span("engine.admit", rid=req.id,
+                              prompt_len=len(req.prompt)):
+                        self._admit(i, req)
+                    admitted = True
+            active = [r for r in self.slots if r is not None]
+            if not active:
+                return admitted
+            with span("engine.decode", active=len(active),
+                      pending=len(self.pending)):
+                self._decode_tick()
+            return True
 
     # -- internals ------------------------------------------------------------
 
@@ -179,19 +186,21 @@ class ServingEngine:
                 toks[i, 0] = req._next_input  # type: ignore[attr-defined]
         logits, self.cache = self.programs["decode"](self.params, self.cache,
                                                      jnp.asarray(toks))
-        nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+        with span("engine.sample"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         self.stats["decode_ticks"] += 1
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(nxt[i])
-            req.generated.append(tok)
-            req._next_input = tok  # type: ignore[attr-defined]
-            self.stats["tokens"] += 1
-            pos = int(np.asarray(self.cache["pos"])[i])
-            if (len(req.generated) >= req.max_new_tokens
-                    or (req.eos_id is not None and tok == req.eos_id)
-                    or pos >= self.max_len - 1):
-                req.done = True
-                self.finished[req.id] = req
-                self.slots[i] = None
+        with span("engine.retire"):
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                tok = int(nxt[i])
+                req.generated.append(tok)
+                req._next_input = tok  # type: ignore[attr-defined]
+                self.stats["tokens"] += 1
+                pos = int(np.asarray(self.cache["pos"])[i])
+                if (len(req.generated) >= req.max_new_tokens
+                        or (req.eos_id is not None and tok == req.eos_id)
+                        or pos >= self.max_len - 1):
+                    req.done = True
+                    self.finished[req.id] = req
+                    self.slots[i] = None
