@@ -31,9 +31,12 @@ so the generic controller drives it with the plain SlurmAdapter.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
+import queue
 import threading
 import time
+from concurrent.futures import Future
 from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
@@ -193,12 +196,18 @@ def serve_job(spec: Dict[str, Any], job: B.ClusterJob,
     ``POST /.../invoke`` route until cancelled.
 
     The payload thread is the engine pump (continuous batching over the
-    shared KV cache); REST worker threads call ``job.handler`` which enqueues
-    a request and parks on a condition variable until the pump moves it to
-    ``finished``.  A replica killed mid-request raises out of the handler
-    (HTTP 500), which the service router treats as a replica fault and
-    retries elsewhere — accepted requests are never silently dropped.
-    Serve jobs NEVER auto-complete: only a cancel ends them.
+    shared KV cache) and the only thread that touches the engine.  REST
+    worker threads call ``job.handler``, which puts the request and a
+    ``Future`` for its result on the pump's inbox and waits on that future
+    alone.  Before every engine tick the pump submits all the inbox holds,
+    so a request joins a running batch at the next tick; after the tick it
+    hands each finished request to its future.  No lock is held across
+    ``eng.step()``.  A replica killed mid-request fails every outstanding
+    future (in the inbox, queued in the engine or in a slot), and a waiter
+    also sees the cancel itself, so the handler raises (HTTP 500), which the
+    service router treats as a replica fault and retries elsewhere —
+    accepted requests are never silently dropped.  Serve jobs NEVER
+    auto-complete: only a cancel ends them.
 
     The handler is installed (and the replica turns ready) only once the
     engine's programs are compiled; ``engine.json`` among the job's outputs
@@ -230,52 +239,77 @@ def serve_job(spec: Dict[str, Any], job: B.ClusterJob,
         "mosaic": {name: "tpu_custom_call" in prog.as_text()
                    for name, prog in eng.programs.items()},
     }).encode()
-    cond = threading.Condition()
-    results: Dict[int, Any] = {}
+    # (prompt, max_new_tokens, eos_id, result) from the handlers
+    inbox: queue.SimpleQueue = queue.SimpleQueue()
+    tickets = itertools.count()
+    # set once the pump stops: a request put after its last drain of the
+    # inbox is failed by its own waiter
+    stopped = threading.Event()
 
     def handler(body: Any) -> Dict[str, Any]:
-        with span("replica.request"), contextlib.ExitStack() as enqueue:
+        with span("replica.request"):
             body = body or {}
             prompt = [int(t) for t in body.get("prompt", [])]
-            enqueue.enter_context(span("replica.enqueue"))
-            with cond:
-                if job._cancel.is_set():
+            max_new = int(body.get("max_new_tokens", 8))
+            result: Future = Future()
+            with span("replica.enqueue"):
+                if job._cancel.is_set() or stopped.is_set():
                     raise RuntimeError("replica shutting down")
-                rid = eng.submit(
-                    prompt, max_new_tokens=int(body.get("max_new_tokens", 8)),
-                    eos_id=body.get("eos_id"))
-                cond.notify_all()
-                enqueue.close()  # ends replica.enqueue; the lock stays held
-                with span("replica.wait", rid=rid):
-                    while rid not in results:
-                        if job._cancel.is_set():
-                            raise RuntimeError("replica cancelled mid-request")
-                        cond.wait(timeout=0.05)
-                    req = results.pop(rid)
+                inbox.put((prompt, max_new, body.get("eos_id"), result))
+            with span("replica.wait", rid=next(tickets)):
+                while True:
+                    try:
+                        req = result.result(timeout=0.05)
+                        break
+                    except TimeoutError:
+                        if job._cancel.is_set() or stopped.is_set():
+                            raise RuntimeError(
+                                "replica cancelled mid-request") from None
             return {"tokens": req.generated, "served_by": job.id,
                     "arch": arch, "device": device.id}
+
+    def drained() -> Iterator[tuple]:
+        while True:
+            try:
+                yield inbox.get_nowait()
+            except queue.Empty:
+                return
+
+    outstanding: Dict[int, Future] = {}  # engine rid -> its handler's result
+
+    def submit(prompt, max_new, eos_id, result: Future) -> None:
+        try:
+            rid = eng.submit(prompt, max_new_tokens=max_new, eos_id=eos_id)
+        except ValueError as e:  # too long, or inexact for a recurrent family
+            result.set_exception(e)
+        else:
+            outstanding[rid] = result
 
     job.handler = handler
     try:
         while not job._cancel.is_set():
-            with cond:
-                busy = (bool(eng.pending)
-                        or any(s is not None for s in eng.slots))
-                if not busy:
-                    with span("replica.idle"):
-                        cond.wait(timeout=0.02)
-                    continue
-                with span("replica.step"):
-                    eng.step()
-                    if eng.finished:
-                        results.update(eng.finished)
-                        eng.finished.clear()
-                        cond.notify_all()
+            arrived = []
+            if not eng.pending and all(s is None for s in eng.slots):
+                with span("replica.idle"):
+                    try:
+                        arrived.append(inbox.get(timeout=0.02))
+                    except queue.Empty:
+                        continue
+            with span("replica.step"):
+                for item in itertools.chain(arrived, drained()):
+                    submit(*item)
+                eng.step()
+                for rid, req in eng.finished.items():
+                    outstanding.pop(rid).set_result(req)
+                eng.finished.clear()
         return -1
     finally:
         job.handler = None
-        with cond:
-            cond.notify_all()  # release parked handlers to see the cancel
+        stopped.set()
+        waiting = list(outstanding.values())
+        waiting += [item[-1] for item in drained()]
+        for result in waiting:
+            result.set_exception(RuntimeError("replica cancelled mid-request"))
 
 
 def jax_train_payload(store: ObjectStore, devices: DevicePool) -> B.Payload:

@@ -70,7 +70,9 @@ class ServingEngine:
         self.pending: deque = deque()
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.finished: Dict[int, Request] = {}
-        self.stats = {"prefills": 0, "decode_ticks": 0, "tokens": 0}
+        # joins: admissions while another slot was decoding (see ``step``)
+        self.stats = {"prefills": 0, "decode_ticks": 0, "tokens": 0,
+                      "joins": 0}
 
         self.cache = DEC.init_cache(cfg, max_batch, max_len)
         self._cache_axes = [
@@ -132,13 +134,18 @@ class ServingEngine:
         """One engine tick: admit into free slots, then decode.  Returns
         False when fully idle."""
         with span("engine.step"):
+            # a slot held on entry has been through a decode tick: it was
+            # filled by an earlier step, which then decoded
+            decoding = sum(s is not None for s in self.slots)
             admitted = False
             for i, slot in enumerate(self.slots):
                 if slot is None and self.pending:
                     req = self.pending.popleft()
                     with span("engine.admit", rid=req.id,
-                              prompt_len=len(req.prompt)):
+                              prompt_len=len(req.prompt), decoding=decoding):
                         self._admit(i, req)
+                    if decoding:
+                        self.stats["joins"] += 1
                     admitted = True
             active = [r for r in self.slots if r is not None]
             if not active:

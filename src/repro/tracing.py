@@ -18,19 +18,25 @@ span                   thread  brackets (args)
 ``router.request``     client  ``ServiceEndpoint.request``: the whole call,
                                picks and retries included
 ``replica.request``    client  ``serve_job``'s handler, entry to return
-``replica.enqueue``    client  taking the replica's lock, ``submit`` and the
-                               wake-up of the pump
-``replica.wait``       client  from submit until the result is popped
-                               (``rid``)
-``replica.step``       pump    one locked pump iteration that steps the
-                               engine: ``ServingEngine.step`` and the
-                               hand-off of its finished requests
-``replica.idle``       pump    one wait on the lock's condition taken because
+``replica.enqueue``    client  the cancel check and the put of the request
+                               and its result on the pump's inbox, a queue
+                               the pump never holds across device work
+``replica.wait``       client  from that put until the result is handed
+                               back (``rid``: the handler's ticket, not the
+                               engine's request id)
+``replica.step``       pump    one pump iteration that steps the engine: the
+                               drain of the inbox into ``submit``,
+                               ``ServingEngine.step`` and the hand-off of its
+                               finished requests; no lock is held
+``replica.idle``       pump    one blocking wait on the inbox, taken because
                                the engine has nothing to do
 ``engine.step``        pump    ``ServingEngine.step``
 ``engine.admit``       pump    one admission: pad, prefill dispatch, ``pos``
                                rewind, insert dispatch (``rid``,
-                               ``prompt_len``)
+                               ``prompt_len``, ``decoding``: the other slots
+                               already decoding when it began; 0 at a wave's
+                               start, 1 or more when it joins a running
+                               batch, counted in the engine's ``joins``)
 ``engine.decode``      pump    one decode tick (``active``, ``pending``)
 ``engine.sample``      pump    inside ``engine.decode``: the argmax and its
                                pull to the host, where the host waits for

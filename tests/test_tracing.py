@@ -36,6 +36,16 @@ def _host_spans(trace_dir):
     return out
 
 
+def _args(trace_dir, name):
+    """The args of every host event named ``name``, as dicts."""
+    from jax.profiler import ProfileData
+
+    return [dict(e.stats)
+            for plane in ProfileData.from_file(
+                devtrace.find_xplane(str(trace_dir))).planes
+            for line in plane.lines for e in line.events if e.name == name]
+
+
 def _inside(inner, outers):
     return any(o.start_ns <= inner.start_ns and inner.end_ns <= o.end_ns
                for o in outers)
@@ -43,8 +53,9 @@ def _inside(inner, outers):
 
 @pytest.fixture(scope="module")
 def engine_trace(tmp_path_factory):
-    """A smoke engine, 2 slots, 5 requests of 4 tokens, run to the end
-    under the profiler: (engine, generated tokens, spans, trace path)."""
+    """A smoke engine, 2 slots, 5 requests of 4, 2, 6, 3 and 5 tokens, run
+    to the end under the profiler: (engine, generated tokens, spans, trace
+    path).  The last three are admitted while another slot decodes."""
     from repro.configs.base import get_smoke_config
     from repro.serving import ServingEngine
     from repro.steps import init_model
@@ -53,8 +64,8 @@ def engine_trace(tmp_path_factory):
     _, params = init_model(cfg, max_seq=64)
     eng = ServingEngine(cfg, params, max_batch=2, max_len=48, prefill_len=8)
     rng = np.random.RandomState(0)
-    for _ in range(5):
-        eng.submit(list(rng.randint(1, cfg.vocab, size=8)), max_new_tokens=4)
+    for n in (4, 2, 6, 3, 5):
+        eng.submit(list(rng.randint(1, cfg.vocab, size=8)), max_new_tokens=n)
     trace_dir = tmp_path_factory.mktemp("engine-trace")
     _start(trace_dir)
     try:
@@ -85,20 +96,24 @@ def test_engine_spans_nest(engine_trace):
 def test_decode_batch_from_the_spans_is_tokens_per_tick(engine_trace):
     """Tokens over engine.decode spans is the mean active slots per tick,
     and the ticks' ``active`` args add up to the tokens."""
-    from jax.profiler import ProfileData
-
     eng, results, spans, trace_dir = engine_trace
     tokens = sum(len(t) for t in results.values())
     assert tokens == eng.stats["tokens"] == 20
     batch = tokens / len(spans["engine.decode"])
     assert batch == eng.stats["tokens"] / eng.stats["decode_ticks"]
     assert 1 < batch <= 2
-    active = [dict(e.stats)["active"]
-              for plane in ProfileData.from_file(
-                  devtrace.find_xplane(str(trace_dir))).planes
-              for line in plane.lines for e in line.events
-              if e.name == "engine.decode"]
+    active = [a["active"] for a in _args(trace_dir, "engine.decode")]
     assert sum(active) == tokens
+
+
+def test_admit_spans_count_the_joins(engine_trace):
+    """Each ``engine.admit`` carries ``decoding``, the slots already
+    decoding when it began; those with one or more are the engine's
+    ``joins``."""
+    eng, _, _, trace_dir = engine_trace
+    decoding = [a["decoding"] for a in _args(trace_dir, "engine.admit")]
+    assert decoding == [0, 0, 1, 1, 1]
+    assert sum(d >= 1 for d in decoding) == eng.stats["joins"] == 3
 
 
 def test_request_spans_through_a_bridge_service(tmp_path):
