@@ -2,13 +2,15 @@
 what it served, and the result line."""
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import shutil
 import sys
 import tempfile
 import time
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Dict, Iterator, List, Optional, Tuple
 
 from bench import manifest, reference, traffic, work
 from bench.devtrace import DeviceTrace, reduce_dir
@@ -31,9 +33,12 @@ class Context:
     t0: float                      # window start (time.perf_counter)
     t_end: float                   # last answer of the window
     setup_s: float
-    shape: work.Shape
+    cfg: dict                      # the configuration file's contents
+    family: ModuleType             # its model family (bench/models/)
     peak: Dict[str, float]
     trace: Optional[DeviceTrace] = None
+    # requests the router sent each replica in the window
+    replica_requests: Optional[List[int]] = None
 
     @property
     def window_s(self) -> float:
@@ -44,8 +49,9 @@ class Context:
         return [o for o in self.outcomes if o.ok]
 
     def work(self) -> Dict[str, float]:
-        return work.totals(self.shape, ((len(o.request.prompt), len(o.tokens))
-                                        for o in self.served))
+        return work.totals(self.family, self.cfg,
+                           ((len(o.request.prompt), len(o.tokens))
+                            for o in self.served))
 
     # -- device-trace readings (None without a trace or without the event)
 
@@ -66,16 +72,18 @@ class Context:
         s = sum(self.trace.module_time(p)[0] for p in STEP_PROGRAMS)
         if s <= 0:
             return None
-        return 100.0 * self.work()["flops"] / (s * self.peak["flops"])
+        return (100.0 * self.work().get("flops", 0.0)
+                / (s * self.peak["flops"]))
 
     def roofline(self, kernel: str, key: str) -> Optional[float]:
         """Share of ``kernel``'s device time that its useful work needs at
-        the peaks; ``key`` is "flash" or "decode" (work.totals)."""
+        the peaks; ``key`` names the kernel's pair of the family's useful
+        work, ``<key>_flops`` and ``<key>_bytes`` (work.totals)."""
         if self.trace is None:
             return None
         s, n = self.trace.kernel_time(kernel)
         w = self.work()
-        if not n or s <= 0 or w[key + "_bytes"] <= 0:
+        if not n or s <= 0 or w.get(key + "_bytes", 0.0) <= 0:
             return None
         least = work.least_time(w[key + "_flops"], w[key + "_bytes"],
                                 self.peak)
@@ -103,14 +111,17 @@ def window(svc: Service, mix: dict, seconds: float, seed: int, vocab: int):
                                   seconds)
 
 
-def compared_gaps(cfg: dict, seed: int, outcomes: List[traffic.Outcome],
+def compared_gaps(cell: manifest.Cell, seed: int,
+                  outcomes: List[traffic.Outcome],
                   control: bool = False) -> dict:
     """The reference's gaps (``bench/reference.py: gaps``) over the sample
     of served requests that a run compares."""
+    cfg = cell.config
     done = [(o.request.prompt, o.tokens) for o in outcomes
             if o.ok and o.tokens]
     picked = reference.sample(done, seed, int(cfg["correct"]["sample_tokens"]))
-    return reference.gaps(cfg, seed, [done[i] for i in picked], control)
+    return reference.gaps(cell.family, cfg, seed, [done[i] for i in picked],
+                          control)
 
 
 def check(cfg: dict, outcomes: List[traffic.Outcome], gaps
@@ -149,6 +160,56 @@ class Window:
     outcomes: List[traffic.Outcome]
     peak_bytes: Optional[int]
     trace: Optional[DeviceTrace]
+    devices: List[int]             # each replica's device id
+    warm_requests: List[int]       # requests each replica took in warm-up
+    replica_requests: List[int]    # and in the window
+
+
+def warm_up(svc: Service, mix: dict, seed: int, vocab: int) -> List[int]:
+    """The mix's warm-up requests, all at once, through the router; then,
+    one at a time, more of them until every replica has taken its share
+    (the router sends an idle service's next request to the replica that
+    has taken the fewest).  Returns the requests each replica took."""
+    reqs = traffic.warmup_requests(mix, seed, vocab)
+    share = max(1, len(reqs) // svc.replicas)
+    warm = traffic.run_concurrent(svc.send, reqs)
+    extra = 0
+    while min(svc.requests().values()) < share and extra < len(reqs):
+        warm += traffic.run_concurrent(svc.send, [reqs[extra]])
+        extra += 1
+    bad = [o.error for o in warm if not o.ok]
+    if bad:
+        raise RuntimeError(f"warm-up failed: {bad[0]}")
+    took = list(svc.requests().values())
+    if min(took) < share:
+        raise RuntimeError(f"warm-up did not reach every replica: {took}")
+    return took
+
+
+@contextlib.contextmanager
+def warmed_service(cell: manifest.Cell, seed: int
+                   ) -> Iterator[Tuple[Service, List[int]]]:
+    """The cell's service, one replica per chip, set up and warmed up; with
+    the warm-up requests each replica took."""
+    cfg = cell.config
+    with Service(job_script(cfg, seed), replicas=cell.chips) as svc:
+        for e in svc.engines:
+            log(f"replica ready on device {e.get('device')} "
+                f"({e.get('device_kind')}): weights "
+                f"{float(e.get('init_s', 0.0)):.3f}s, compile "
+                f"{float(e.get('compile_s', 0.0)):.3f}s, Mosaic "
+                f"{e.get('mosaic')}")
+        yield svc, warm_up(svc, cell.traffic, seed, int(cfg["vocab_size"]))
+
+
+def peak_bytes() -> Optional[int]:
+    """``peak_bytes_in_use`` of the fullest local device."""
+    import jax
+
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
 
 
 def serve_window(cell: manifest.Cell, seed: int, seconds: float,
@@ -157,40 +218,36 @@ def serve_window(cell: manifest.Cell, seed: int, seconds: float,
     the memory peak and stop the service."""
     import jax
 
-    cfg, mix = cell.config, cell.traffic
-    vocab = int(cfg["vocab_size"])
+    mix = cell.traffic
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
-        with Service(job_script(cfg, seed)) as svc:
-            init_s = float(svc.engine.get("init_s", 0.0))
-            compile_s = float(svc.engine.get("compile_s", 0.0))
-            log(f"replica ready on device {svc.engine.get('device')} "
-                f"({svc.engine.get('device_kind')}): weights {init_s:.3f}s, "
-                f"compile {compile_s:.3f}s, Mosaic "
-                f"{svc.engine.get('mosaic')}")
-            warm = traffic.run_concurrent(
-                svc.send, traffic.warmup_requests(mix, seed, vocab))
-            bad = [o.error for o in warm if not o.ok]
-            if bad:
-                raise RuntimeError(f"warm-up failed: {bad[0]}")
+        with warmed_service(cell, seed) as (svc, warm):
             if trace_dir:
                 _start_trace(trace_dir)
             setup_s = time.time() - t_start
-            t0, outcomes = window(svc, mix, seconds, seed, vocab)
+            before = svc.requests()
+            t0, outcomes = window(svc, mix, seconds, seed,
+                                  int(cell.config["vocab_size"]))
             t_end = max(o.done for o in outcomes)
             if trace_dir:
                 jax.profiler.stop_trace()
-            peak = (jax.devices()[0].memory_stats() or {}).get(
-                "peak_bytes_in_use")
-        log(f"setup {setup_s:.3f}s (weights {init_s:.3f}s, compile "
-            f"{compile_s:.3f}s); window {t_end - t0:.3f}s; "
-            f"peak_bytes_in_use {peak}")
+            took = [n - before[j] for j, n in svc.requests().items()]
+            devices = [e["device"] for e in svc.engines]
+            peak = peak_bytes()
+        init_s = [float(e.get("init_s", 0.0)) for e in svc.engines]
+        compile_s = [float(e.get("compile_s", 0.0)) for e in svc.engines]
+        log(f"setup {setup_s:.3f}s (weights {max(init_s):.3f}s, compile "
+            f"{max(compile_s):.3f}s, slowest replica); window "
+            f"{t_end - t0:.3f}s; peak_bytes_in_use {peak} (fullest device)")
+        log(f"replicas on devices {devices}: warm-up requests {warm}, "
+            f"window requests {took}")
         _log_traffic(mix, t0, outcomes)
         dtrace = reduce_dir(trace_dir) if trace_dir else None
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    return Window(t0, t_end, setup_s, outcomes, peak, dtrace)
+    return Window(t0, t_end, setup_s, outcomes, peak, dtrace, devices, warm,
+                  took)
 
 
 def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
@@ -200,16 +257,16 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
 
     w = serve_window(cell, seed, seconds, trace, t_start)
     t = time.perf_counter()
-    gaps = compared_gaps(cell.config, seed, w.outcomes)["served"]
+    gaps = compared_gaps(cell, seed, w.outcomes)["served"]
     log(f"check: {len(gaps)} served tokens against the reference in "
         f"{time.perf_counter() - t:.3f}s; exact argmax share "
         f"{float((gaps == 0).mean()) if len(gaps) else 0:.4f}, widest gap "
         f"{float(gaps.max()) if len(gaps) else math.inf:.6f}")
     checks = check(cell.config, w.outcomes, gaps)
     ctx = Context(loop=cell.traffic["loop"], outcomes=w.outcomes, t0=w.t0,
-                  t_end=w.t_end, setup_s=w.setup_s,
-                  shape=work.Shape.of(cell.config),
-                  peak=work.peaks(device_kind), trace=w.trace)
+                  t_end=w.t_end, setup_s=w.setup_s, cfg=cell.config,
+                  family=cell.family, peak=work.peaks(device_kind),
+                  trace=w.trace, replica_requests=w.replica_requests)
     metrics = manifest.read_metrics(
         cell.per_layer if trace else cell.end_to_end, ctx)
     dev = jax.devices()[0]
@@ -218,6 +275,9 @@ def run(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     result = {"correct": passed(checks), "attempted": len(w.outcomes),
               "failed": checks["failed"]["value"], "metrics": metrics,
               "device": device}
+    result["replicas"] = {"devices": w.devices,
+                          "warm_requests": w.warm_requests,
+                          "window_requests": w.replica_requests}
     if w.trace is not None:
         device["busy_s"] = w.trace.busy_s()
         device["window_s"] = ctx.window_s

@@ -36,7 +36,7 @@ def readings(cell: manifest.Cell, seed: int, seconds: float,
     """The program's and (with ``control``) the control's checks of one
     seed."""
     w = C.serve_window(cell, seed, seconds, False, t_start)
-    g = C.compared_gaps(cell.config, seed, w.outcomes, control=control)
+    g = C.compared_gaps(cell, seed, w.outcomes, control=control)
     row = {"seed": seed, "requests": len(w.outcomes)}
     for side in ("served", "control") if control else ("served",):
         checks = C.check(cell.config, w.outcomes, g[side])

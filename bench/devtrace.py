@@ -149,20 +149,20 @@ class DeviceTrace:
         return [[k, v / 1e9] for k, v in top]
 
     def idle_gaps(self, n: int = 10) -> List[List]:
-        """The ``n`` longest stretches with no operation on the first
-        device, each named by the host event that covers most of it (what
-        the host was doing) and the program that ran next:
-        [["<host event> -> <next program>", seconds], ...]."""
-        if not self.devices:
-            return []
-        busy = self.busy(self.devices[0])
-        gaps = sorted(((b[0] - a[1], a[1], b[0])
-                       for a, b in zip(busy, busy[1:])), reverse=True)[:n]
-        mods = sorted((e.start_ns, e.name) for e in self.modules
-                      if e.plane == self.devices[0])
+        """The ``n`` longest stretches with no operation on a device, over
+        every device, each named by the host event that covers most of it
+        (what the host was doing) and the program that ran next on that
+        device: [["<host event> -> <next program>", seconds], ...]."""
+        gaps = []
+        for plane in self.devices:
+            busy = self.busy(plane)
+            gaps += [(b[0] - a[1], a[1], b[0], plane)
+                     for a, b in zip(busy, busy[1:])]
         out = []
-        for length, s, e in gaps:
-            nxt = next((name for t, name in mods if t >= e - 1), "end")
+        for length, s, e, plane in sorted(gaps, reverse=True)[:n]:
+            nxt = min(((t.start_ns, t.name) for t in self.modules
+                       if t.plane == plane and t.start_ns >= e - 1),
+                      default=(0, "end"))[1]
             out.append([f"{self._host_at(s, e)} -> {nxt.split('(')[0]}",
                         length / 1e9])
         return out

@@ -1,35 +1,36 @@
-"""The plain reference a served model is compared with, and its control.
+"""The plain reference a served model is compared with, and its control:
+the parts that belong to no model family.
 
-The reference is a dense pre-norm decoder written from the configuration
-file alone, in float32 with ``precision=highest`` and with no kernel, no
-cache and no batching: RMSNorm, rotary embeddings (half rotation), causal
-grouped-query attention, a SiLU-gated MLP, an untied output head.  It runs
-teacher-forced over a request's prompt and served tokens.
+A configuration's family (its ``model_type``) is a module
+``bench/models/<model_type>.py`` (``manifest.family``) that gives the
+reference's forward (``forward(w, toks, rows, cfg, dense)``: teacher-forced
+float32 logits with ``precision=highest``, no kernel, no cache and no
+batching) and its weight leaves (``weight_leaves(cfg)``).  It runs over a
+request's prompt and served tokens.
 
 Its weights are made here from the seed, by the same rule the program uses
-to make its own (``weight_leaves``): one key per leaf, split from
-``PRNGKey(seed)`` in the leaves' sorted-path order; a matmul leaf is a
-truncated normal in [-2, 2] over sqrt(fan-in), the embedding a truncated
-normal, the norm scales ones; each cast to its stored type.  Nothing the
-program made is read.
+to make its own: one key per leaf, split from ``PRNGKey(seed)`` in the
+leaves' sorted-path order; a matmul leaf is a truncated normal in [-2, 2]
+over sqrt(fan-in), the embedding a truncated normal, the norm scales ones;
+each cast to its stored type.  Nothing the program made is read.
 
 The comparison reads, at each served token, the gap by which its logit
 lies below the reference's best at that position.  The control puts the
 reference in the program's place at the precision below the configured
-bfloat16: int8, every weight matmul with per-output-channel weight scales
-and per-token activation scales, accumulated in int32 (attention stays in
-float32).  Its gap is read at the token the int8 logits put first.
+bfloat16: int8, every weight matmul (the family's ``dense``) with
+per-output-channel weight scales and per-token activation scales,
+accumulated in int32 (attention stays in float32).  Its gap is read at the
+token the int8 logits put first.
 """
 from __future__ import annotations
 
 import functools
+import json
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from bench.work import Shape
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -38,33 +39,11 @@ ROWS = 128    # and the compared positions to a multiple of this, so that
 #               runs share a few compiled programs
 
 
-def weight_leaves(s: Shape, dtype) -> List[Tuple[str, tuple, str, int, object]]:
-    """(name, shape, init, fan-in, dtype) of every leaf, in the order the
-    program flattens its parameter tree (sorted paths)."""
-    L, d, H, K, D, F, V = (s.layers, s.d, s.heads, s.kv_heads, s.head_dim,
-                           s.d_ff, s.vocab)
-    return [
-        ("wk", (L, d, K, D), "normal", d, dtype),
-        ("wo", (L, H, D, d), "normal", H * D, dtype),
-        ("wq", (L, d, H, D), "normal", d, dtype),
-        ("wv", (L, d, K, D), "normal", d, dtype),
-        ("ln_attn", (L, d), "ones", 0, F32),
-        ("ln_mlp", (L, d), "ones", 0, F32),
-        ("w1", (L, d, F), "normal", d, dtype),
-        ("w2", (L, F, d), "normal", F, dtype),
-        ("w3", (L, d, F), "normal", d, dtype),
-        ("embedding", (V, d), "embed", 0, dtype),
-        ("lm_head", (d, V), "normal", d, dtype),
-        ("ln_f", (d,), "ones", 0, F32),
-    ]
-
-
-def make_weights(cfg: dict, seed: int) -> Dict[str, jax.Array]:
-    """All weights of the configuration under ``seed``, made on the device
-    in one jitted call, in their stored types."""
-    if cfg.get("tie_word_embeddings"):
-        raise NotImplementedError("the reference has an untied head")
-    leaves = weight_leaves(Shape.of(cfg), jnp.dtype(cfg["torch_dtype"]))
+def make_weights(family, cfg: dict, seed: int) -> Dict[str, jax.Array]:
+    """All weights of the configuration (``family.weight_leaves``) under
+    ``seed``, made on the device in one jitted call, in their stored
+    types."""
+    leaves = family.weight_leaves(cfg)
 
     @jax.jit
     def make(key):
@@ -81,20 +60,6 @@ def make_weights(cfg: dict, seed: int) -> Dict[str, jax.Array]:
         return out
 
     return make(jax.random.PRNGKey(seed))
-
-
-def _rms(x, scale, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
-
-
-def _rope(x, pos, theta):
-    half = x.shape[-1] // 2
-    freqs = jnp.exp(-jnp.log(theta) * jnp.arange(half, dtype=F32) / half)
-    ang = pos.astype(F32)[:, None, None] * freqs
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * jnp.cos(ang) - x2 * jnp.sin(ang),
-                            x2 * jnp.cos(ang) + x1 * jnp.sin(ang)], axis=-1)
 
 
 def dense_f32(x, w):
@@ -114,50 +79,19 @@ def dense_int8(x, w):
     return y.astype(F32) * sx * sw
 
 
-def forward(w, toks, rows, *, shape: Shape, eps: float, theta: float,
-            dense=dense_f32):
-    """Logits (R, V) at ``rows`` of one right-padded sequence ``toks``."""
-    H, K, D = shape.heads, shape.kv_heads, shape.head_dim
-    S = toks.shape[0]
-    pos = jnp.arange(S)
-    causal = pos[None, :] <= pos[:, None]
-    x = w["embedding"][toks].astype(F32)
-
-    def layer(x, lw):
-        lw = jax.tree_util.tree_map(lambda a: a.astype(F32), lw)
-        h = _rms(x, lw["ln_attn"], eps)
-        q = dense(h, lw["wq"].reshape(-1, H * D)).reshape(S, H, D)
-        k = dense(h, lw["wk"].reshape(-1, K * D)).reshape(S, K, D)
-        v = dense(h, lw["wv"].reshape(-1, K * D)).reshape(S, K, D)
-        q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-        q = q.reshape(S, K, H // K, D)
-        sc = jnp.einsum("qkgd,skd->kgqs", q, k, precision=HIGHEST) / np.sqrt(D)
-        p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-        a = jnp.einsum("kgqs,skd->qkgd", p, v, precision=HIGHEST)
-        x = x + dense(a.reshape(S, H * D), lw["wo"].reshape(H * D, -1))
-        h = _rms(x, lw["ln_mlp"], eps)
-        m = jax.nn.silu(dense(h, lw["w1"])) * dense(h, lw["w3"])
-        return x + dense(m, lw["w2"]), None
-
-    blocks = {n: w[n] for n in ("wq", "wk", "wv", "wo", "ln_attn", "ln_mlp",
-                                "w1", "w2", "w3")}
-    x, _ = jax.lax.scan(layer, x, blocks)
-    x = _rms(x[rows], w["ln_f"].astype(F32), eps)
-    return dense(x, w["lm_head"].astype(F32))
-
-
 @functools.lru_cache(maxsize=None)
-def _programs(shape: Shape, eps: float, theta: float):
+def _programs(family, cfg_json: str):
     """Jitted (reference gaps, control gaps) over one padded sequence."""
+    cfg = json.loads(cfg_json)
+
     def ref_gaps(w, toks, rows, served):
-        ref = forward(w, toks, rows, shape=shape, eps=eps, theta=theta)
+        ref = family.forward(w, toks, rows, cfg, dense_f32)
         best = jnp.max(ref, axis=-1)
         return best - jnp.take_along_axis(ref, served[:, None], 1)[:, 0]
 
     def ctrl_gaps(w, toks, rows, served):
-        ref = forward(w, toks, rows, shape=shape, eps=eps, theta=theta)
-        low = forward(w, toks, rows, shape=shape, eps=eps, theta=theta,
-                      dense=dense_int8)
+        ref = family.forward(w, toks, rows, cfg, dense_f32)
+        low = family.forward(w, toks, rows, cfg, dense_int8)
         first = jnp.argmax(low, axis=-1)
         best = jnp.max(ref, axis=-1)
         return (best - jnp.take_along_axis(ref, served[:, None], 1)[:, 0],
@@ -179,16 +113,14 @@ def _padded(prompt: Sequence[int], tokens: Sequence[int], rows_pad: int):
     return toks, rows, served, o
 
 
-def gaps(cfg: dict, seed: int, cases: Sequence[Tuple[Sequence[int],
-                                                     Sequence[int]]],
+def gaps(family, cfg: dict, seed: int,
+         cases: Sequence[Tuple[Sequence[int], Sequence[int]]],
          control: bool = False) -> Dict[str, np.ndarray]:
     """Per served token of each (prompt, served tokens) case, the gap below
     the reference's best logit; with ``control``, also the gap of the token
     the int8 control puts first."""
-    shape = Shape.of(cfg)
-    ref_fn, ctrl_fn = _programs(shape, float(cfg["rms_norm_eps"]),
-                                float(cfg["rope_theta"]))
-    w = make_weights(cfg, seed)
+    ref_fn, ctrl_fn = _programs(family, json.dumps(cfg, sort_keys=True))
+    w = make_weights(family, cfg, seed)
     rows_pad = -(-max(len(t) for _, t in cases) // ROWS) * ROWS
     out: Dict[str, list] = {"served": [], "control": []}
     for prompt, tokens in cases:

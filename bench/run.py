@@ -3,15 +3,17 @@
 
     python3 bench/run.py --workload phi3-code --seed 7 --seconds 51 --trace 0
 
-Set-up (the replica's weights and compile, and a warm-up that runs every
-program shape), then a measured window of ``--seconds`` of the cell's
+Set-up (one replica per chip the cell asks for: each one's weights and
+compile, and a warm-up that runs every program shape on every replica),
+then a measured window of ``--seconds`` of the cell's
 traffic through ``ServiceHandle.router()``, then the check of what was
 served against the plain reference.  With ``--trace 0`` the result carries
 the cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics read
 from a profiler trace of the window.  The last line of standard output is
 one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` (``busy_s`` and ``window_s`` too when traced), ``breakdown``
-when traced, and ``checks`` last: each compared number with its limit
+``device`` (``busy_s`` and ``window_s`` too when traced), ``replicas``
+(each replica's device, and the requests it took in the warm-up and in the
+window), ``breakdown`` when traced, and ``checks`` last: each compared number with its limit
 (``compared_tokens`` has to reach its limit, the others may not pass
 theirs).
 

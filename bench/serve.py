@@ -1,4 +1,4 @@
-"""The system under test: one ``BridgeService`` replica behind
+"""The system under test: ``BridgeService`` replicas, one per chip, behind
 ``ServiceHandle.router()``, started and stopped.
 
 This is the one module of the benchmark that imports the program.  The
@@ -29,24 +29,26 @@ def job_script(cfg: dict, seed: int) -> dict:
 
 
 class Service:
-    """A ``BridgeEnvironment`` with one serving replica; use as a context
+    """A ``BridgeEnvironment`` with ``replicas`` serving replicas, each on a
+    device of its own (``jaxlocal``'s device pool); use as a context
     manager.  ``send`` is the client's request path."""
 
-    def __init__(self, script: dict):
+    def __init__(self, script: dict, replicas: int = 1):
         self.script = script
+        self.replicas = replicas
         self.env = None
         self.handle = None
         self.router = None
-        self.engine: Dict[str, Any] = {}
+        self.engines: List[Dict[str, Any]] = []  # engine.json, by replica
 
     def __enter__(self) -> "Service":
         from repro.core import BridgeEnvironment, HealthProbeSpec
 
-        self.env = BridgeEnvironment(slots=2).start()
+        self.env = BridgeEnvironment(slots=max(2, self.replicas)).start()
         try:
             spec = self.env.make_service_spec(
-                "jaxlocal", replicas=1, script=json.dumps(self.script),
-                updateinterval=0.5,
+                "jaxlocal", replicas=self.replicas,
+                script=json.dumps(self.script), updateinterval=0.5,
                 # a replica makes its weights and compiles before it turns
                 # ready
                 health=HealthProbeSpec(failure_threshold=5,
@@ -55,8 +57,13 @@ class Service:
             self.handle = self.env.bridge.submit_service("bench", spec)
             self.handle.wait_ready(timeout=READY_TIMEOUT_S)
             jobs = self.env.clusters["jaxlocal"].jobs
-            ep = self.handle.endpoints()[0]
-            self.engine = json.loads(jobs[ep["job_id"]].outputs["engine.json"])
+            self.engines = [
+                json.loads(jobs[e["job_id"]].outputs["engine.json"])
+                for e in sorted(self.handle.endpoints(),
+                                key=lambda e: e["replica"])]
+            devices = [e["device"] for e in self.engines]
+            if len(set(devices)) != len(devices):
+                raise RuntimeError(f"replicas share a device: {devices}")
             self.router = self.handle.router(request_timeout=REQUEST_TIMEOUT_S)
         except BaseException:
             self.stop()
@@ -68,8 +75,15 @@ class Service:
                                    "max_new_tokens": req.max_new})
         return out["tokens"]
 
+    def requests(self) -> Dict[str, int]:
+        """Requests the router has sent to each replica so far, by job id
+        (every ready replica, those not yet sent any at 0)."""
+        stats = self.router.stats()
+        return {e["job_id"]: stats.get(e["job_id"], {}).get("requests", 0)
+                for e in self.handle.endpoints()}
+
     def stop(self) -> None:
-        """Kill the service and wait until the replica's payload has
+        """Kill the service and wait until every replica's payload has
         returned, so that its weights and cache can be freed."""
         if self.env is None:
             return
@@ -91,4 +105,3 @@ class Service:
 
     def __exit__(self, *exc) -> None:
         self.stop()
-

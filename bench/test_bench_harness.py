@@ -70,42 +70,110 @@ def test_every_metric_has_a_reader_and_every_file_is_under_paths():
             assert key in cfg
 
 
-def test_a_cell_of_new_files_is_found_by_name(tmp_path):
-    """A later change adds a cell, its configuration, its mix and a metric as
-    new files plus entries: the harness finds all of them by name."""
+TOY_FAMILY = """
+import jax.numpy as jnp
+
+
+def weight_leaves(cfg):
+    d, v = cfg["hidden_size"], cfg["vocab_size"]
+    return [("embedding", (v, d), "embed", 0, jnp.float32),
+            ("lm_head", (d, v), "normal", d, jnp.float32)]
+
+
+def forward(w, toks, rows, cfg, dense):
+    return dense(w["embedding"][toks][rows], w["lm_head"])
+
+
+def request_work(cfg, prompt, out):
+    return {"flops": 2.0 * cfg["hidden_size"] * cfg["vocab_size"] * out,
+            "toy_scan_flops": 197e12 * prompt, "toy_scan_bytes": 1.0}
+"""
+
+
+def _new_files(tmp_path, model_type="toyfam"):
+    """A cell, its configuration, its family, its mix and two metrics, as
+    new files and entries of a copy of ``BENCHMARK.json`` under
+    ``tmp_path``."""
     bench = json.loads(json.dumps(BENCH))
-    (tmp_path / "bench" / "configs").mkdir(parents=True)
-    (tmp_path / "bench" / "traffic").mkdir()
-    (tmp_path / "bench" / "metrics").mkdir()
+    for d in ("configs", "models", "traffic", "metrics"):
+        (tmp_path / "bench" / d).mkdir(parents=True)
     (tmp_path / "bench" / "configs" / "tiny-model.json").write_text(
-        json.dumps({"hidden_size": 8, "vocab_size": 16}))
+        json.dumps({"name": "tiny-model", "model_type": model_type,
+                    "hidden_size": 8, "vocab_size": 16,
+                    "torch_dtype": "float32"}))
+    (tmp_path / "bench" / "models" / "toyfam.py").write_text(TOY_FAMILY)
     (tmp_path / "bench" / "traffic" / "burst.json").write_text(
         json.dumps({"loop": "open", "rate_rps": 3.0,
                     "prompt_tokens": [4, 8], "output_tokens": [2, 4]}))
     (tmp_path / "bench" / "metrics" / "queue_wait_ms.lat.py").write_text(
         "def read(ctx):\n    return 42.0\n")
+    (tmp_path / "bench" / "metrics" / "toy_scan_roofline.py").write_text(
+        "def read(ctx):\n    return ctx.roofline('_toy_scan', 'toy_scan')\n")
     bench["configs"].append({"name": "tiny-model", "source": "x",
                              "file": "bench/configs/tiny-model.json",
                              "reduced": [], "why": "x"})
     bench["workloads"].append({"name": "tiny-burst", "config": "tiny-model",
                                "traffic": "burst", "chips": 1, "why": "x"})
-    bench["per_layer"].append({"name": "queue_wait_ms.lat", "unit": "ms",
-                               "better": "lower", "source": "host_clock",
-                               "layer": "router", "moves": "latency_p90_ms",
-                               "workloads": ["tiny-burst"]})
-    bench["end_to_end"].append({"name": "latency_p90_ms", "unit": "ms",
-                                "better": "lower", "bound": 0.1,
-                                "source": "host_clock",
-                                "workloads": ["tiny-burst"]})
+    for name, unit in (("queue_wait_ms.lat", "ms"),
+                       ("toy_scan_roofline", "%")):
+        bench["per_layer"].append({"name": name, "unit": unit,
+                                   "better": "lower", "source": "host_clock",
+                                   "layer": "router",
+                                   "moves": "latency_p90_ms",
+                                   "workloads": ["tiny-burst"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    cell = manifest.cell("tiny-burst", root=str(tmp_path))
+    return str(tmp_path)
+
+
+def test_a_cell_of_new_files_is_found_by_name(tmp_path):
+    """A later change adds a cell, its configuration, its model family, its
+    mix and metrics as new files plus entries: the harness finds all of
+    them by name, and the family's reference, weights and useful work are
+    the ones used, with no edit to a file that is there."""
+    import numpy as np
+
+    from bench import reference
+
+    root = _new_files(tmp_path)
+    cell = manifest.cell("tiny-burst", root=root)
     assert cell.config["hidden_size"] == 8
     assert cell.traffic["rate_rps"] == 3.0
-    assert [m["name"] for m in cell.per_layer] == ["queue_wait_ms.lat"]
-    got = manifest.read_metrics(cell.per_layer, None, root=str(tmp_path))
-    assert got == {"queue_wait_ms.lat": {"value": 42.0, "unit": "ms"}}
+    assert [m["name"] for m in cell.per_layer] == ["queue_wait_ms.lat",
+                                                   "toy_scan_roofline"]
+    assert [m["name"] for m in cell.end_to_end] == ["setup_s"]
+    # the family's useful work, summed key by key
+    assert work.totals(cell.family, cell.config, [(3, 2), (5, 1)]) == {
+        "flops": 2.0 * 8 * 16 * 3, "toy_scan_flops": 197e12 * 8,
+        "toy_scan_bytes": 2.0}
+    # the family's weights and forward make the reference's gaps
+    cases = [([1, 2, 3], [4, 5]), ([7], [9, 9, 2])]
+    gaps = reference.gaps(cell.family, cell.config, 3, cases)["served"]
+    w = reference.make_weights(cell.family, cell.config, 3)
+    assert w["embedding"].shape == (16, 8) and w["lm_head"].shape == (8, 16)
+    logits = np.asarray(w["embedding"]) @ np.asarray(w["lm_head"])
+    want = [logits[t].max() - logits[t][s] for p, served in cases
+            for t, s in zip(p[-1:] + served[:-1], served)]
+    np.testing.assert_allclose(gaps, want, rtol=1e-5, atol=1e-6)
+    # a metric of a new kernel reads the family's new pair of work keys
+    ctx = Context(loop="open", outcomes=[traffic.Outcome(
+                      traffic.Request(0, [1] * 4, 2), 0.0, 1.0,
+                      tokens=[1, 2])],
+                  t0=0.0, t_end=1.0, setup_s=1.0, cfg=cell.config,
+                  family=cell.family, peak=work.PEAKS["TPU v5 lite"],
+                  trace=DeviceTrace([_ev("XLA Ops", "_toy_scan.3", 0, 8e9)]))
+    got = manifest.read_metrics(cell.per_layer, ctx, root=root)
+    assert got == {"queue_wait_ms.lat": {"value": 42.0, "unit": "ms"},
+                   "toy_scan_roofline": {"value": pytest.approx(50.0),
+                                         "unit": "%"}}
     with pytest.raises(KeyError):
-        manifest.cell("no-such-cell", root=str(tmp_path))
+        manifest.cell("no-such-cell", root=root)
+
+
+def test_a_family_without_a_module_fails_by_name(tmp_path):
+    root = _new_files(tmp_path, model_type="no_such_family")
+    with pytest.raises(FileNotFoundError,
+                       match=r"bench/models/no_such_family\.py"):
+        manifest.cell("tiny-burst", root=root)
 
 
 # -- traffic ----------------------------------------------------------------
@@ -181,10 +249,10 @@ def test_open_mix_sizes_rate_and_range():
 
 
 def _ctx(outcomes, t0, t_end, loop="open"):
-    shape = work.Shape(layers=1, d=8, heads=2, kv_heads=1, head_dim=4,
-                       d_ff=16, vocab=32)
+    cell = manifest.cell("phi3-code")
     return Context(loop=loop, outcomes=outcomes, t0=t0, t_end=t_end,
-                   setup_s=1.0, shape=shape, peak=work.PEAKS["TPU v5 lite"])
+                   setup_s=1.0, cfg=cell.config, family=cell.family,
+                   peak=work.PEAKS["TPU v5 lite"])
 
 
 def test_latency_is_timed_from_the_due_time():
@@ -223,6 +291,39 @@ def test_open_loop_sends_on_schedule_whatever_the_answers():
     assert outs[0].done >= outs[4].sent
 
 
+def test_sweep_judges_each_step_and_takes_the_highest_that_keeps_up():
+    """Per step of the staircase: offered rate, completions in the step's
+    interval shifted by the lowest step's median latency, p90; the knee is
+    the highest rate whose step keeps up, a low step that misses by one
+    answer notwithstanding."""
+    from bench import sweep
+
+    step_s, t0 = 10.0, 0.0
+    mix = {"loop": "open", "rate_rps": 1.0, "prompt_tokens": [8, 16],
+           "output_tokens": [2, 4]}
+    rates = [1.0, 2.0, 3.0]
+    steps = sweep.staircase(mix, rates, step_s, 5, 100)
+    assert [len(s) for s in steps] == [10, 20, 30]
+    assert all(k * step_s <= r.due < (k + 1) * step_s
+               for k, s in enumerate(steps) for r in s)
+    assert len({r.index for s in steps for r in s}) == 60
+    # steps 1 and 2 answered 1 s after due; step 3's queue grows by 2 s a
+    # request, so it neither completes in its interval nor keeps its p90
+    outs = [traffic.Outcome(r, sent=r.due, done=r.due + 1.0, tokens=[1])
+            for s in steps[:2] for r in s]
+    outs += [traffic.Outcome(r, sent=r.due, done=r.due + 1.0 + 2.0 * j,
+                             tokens=[1]) for j, r in enumerate(steps[2])]
+    rows = sweep.judge(rates, steps, outs, t0, step_s, [30, 30])
+    assert [r["keeps_up"] for r in rows] == [True, True, False]
+    assert rows[0]["p50_ms"] == pytest.approx(1000.0)
+    assert rows[1]["completed_rps"] == pytest.approx(2.0)
+    assert rows[-1]["replica_requests"] == [30, 30]
+    assert sweep.knee(rows) == 2.0
+    rows[0]["keeps_up"] = False
+    assert sweep.knee(rows) == 2.0
+    assert sweep.knee([dict(r, keeps_up=False) for r in rows]) is None
+
+
 def test_tokens_per_s_counts_all_work_over_all_time():
     reqs = [traffic.Request(i, [1] * 100, 4) for i in range(3)]
     outs = [traffic.Outcome(r, sent=10.0, done=10.0 + i, tokens=[1] * 4)
@@ -243,9 +344,10 @@ def test_percentile():
 
 # -- useful work, worked by hand ----------------------------------------------
 
-PHI3 = work.Shape.of(manifest.cell("phi3-code").config)
+FAMILY = manifest.cell("phi3-code").family  # bench/models/phi3.py
+PHI3 = FAMILY.Shape.of(manifest.cell("phi3-code").config)
 # grouped-query attention: granite-3-8b's published widths at 20 layers
-GRANITE = work.Shape.of({"num_hidden_layers": 20, "hidden_size": 4096,
+GRANITE = FAMILY.Shape.of({"num_hidden_layers": 20, "hidden_size": 4096,
                          "num_attention_heads": 32, "num_key_value_heads": 8,
                          "intermediate_size": 12800, "vocab_size": 49155})
 
@@ -269,13 +371,13 @@ def test_decode_kv_bytes_at_valid_contexts():
     # phi3, prompt 100, 3 tokens: useful decode steps at positions 100 and
     # 101 attend over 101 and 102 keys; K and V, 32 heads x 96, bf16, 32
     # layers
-    flops, nbytes = work.decode_attn_work(PHI3, 100, 3)
+    flops, nbytes = FAMILY.decode_attn_work(PHI3, 100, 3)
     assert nbytes == 32 * 2 * 32 * 96 * (101 + 102) * 2
     assert flops == 32 * 4 * 32 * 96 * (101 + 102)
     # granite: GQA reads 8 of 32 heads' K/V, 20 layers; one token: no
     # decode step is useful (the first token comes with the prompt)
-    assert work.decode_attn_work(GRANITE, 2000, 1) == (0.0, 0.0)
-    flops, nbytes = work.decode_attn_work(GRANITE, 2000, 2)
+    assert FAMILY.decode_attn_work(GRANITE, 2000, 1) == (0.0, 0.0)
+    flops, nbytes = FAMILY.decode_attn_work(GRANITE, 2000, 2)
     assert nbytes == 20 * 2 * 8 * 128 * 2001 * 2
     assert flops == 20 * 4 * 32 * 128 * 2001
 
@@ -283,7 +385,7 @@ def test_decode_kv_bytes_at_valid_contexts():
 def test_causal_prefill_flops_and_bytes():
     # granite, prompt 1024: sum of 1..1024 = 524,800 query-key pairs per
     # head, 4 FLOPs per pair per head dim
-    flops, nbytes = work.flash_work(GRANITE, 1024)
+    flops, nbytes = FAMILY.flash_work(GRANITE, 1024)
     assert flops == 20 * 4 * 32 * 128 * 524_800
     assert nbytes == 20 * (2 * 32 + 2 * 8) * 128 * 1024 * 2
     assert work.causal_pairs(0, 1023) == 524_800
@@ -297,7 +399,90 @@ def test_step_flops_of_one_request():
                  + 3 * 3072 * 8192)
     want = (2 * 32 * per_layer * 3 + 2 * 3072 * 32064 * 2
             + 32 * 4 * 32 * 96 * 6)
-    assert work.request_flops(PHI3, 2, 2) == want
+    assert FAMILY.request_flops(PHI3, 2, 2) == want
+
+
+# -- the phi3 family, pinned to the values the harness gave before families
+# were split out of bench/work.py and bench/reference.py
+
+# (P, O): flops, flash FLOPs, flash bytes, decode FLOPs, decode bytes of
+# phi3-mini-3.8b
+PINNED_WORK = {
+    (1, 1): (7445151744, 393216, 786432, 0, 0),
+    (2, 2): (22139633664, 1179648, 1572864, 1179648, 1179648),
+    (100, 3): (741927813120, 1985740800, 78643200, 79822848, 79822848),
+    (1125, 4): (8426640900096, 249053184000, 884736000, 1329463296,
+                1329463296),
+    (2000, 42): (15620354211840, 786825216000, 1572864000, 32582270976,
+                 32582270976),
+    (676, 33): (5236604928000, 89978044416, 531628032, 8713666560,
+                8713666560),
+    (1538, 504): (15711368773632, 465367597056, 1209532416, 354039889920,
+                  354039889920),
+}
+WORK_KEYS = ("flops", "flash_flops", "flash_bytes", "decode_flops",
+             "decode_bytes")
+
+
+@pytest.mark.parametrize("prompt,out", sorted(PINNED_WORK))
+def test_phi3_request_work_is_pinned(prompt, out):
+    cfg = manifest.cell("phi3-code").config
+    got = FAMILY.request_work(cfg, prompt, out)
+    assert got == dict(zip(WORK_KEYS, map(float, PINNED_WORK[prompt, out])))
+
+
+def test_phi3_work_totals_and_weight_leaves_are_pinned():
+    import jax.numpy as jnp
+
+    cfg = manifest.cell("phi3-code").config
+    assert work.totals(FAMILY, cfg, sorted(PINNED_WORK)) == {
+        "flops": 45766481412096.0, "flash_flops": 1593211355136.0,
+        "flash_bytes": 4279762944.0, "decode_flops": 396746293248.0,
+        "decode_bytes": 396746293248.0}
+    L, d, H, D, F, V = 32, 3072, 32, 96, 8192, 32064
+    assert [(n, s, i, f, jnp.dtype(dt).name) for n, s, i, f, dt
+            in FAMILY.weight_leaves(cfg)] == [
+        ("wk", (L, d, H, D), "normal", d, "bfloat16"),
+        ("wo", (L, H, D, d), "normal", H * D, "bfloat16"),
+        ("wq", (L, d, H, D), "normal", d, "bfloat16"),
+        ("wv", (L, d, H, D), "normal", d, "bfloat16"),
+        ("ln_attn", (L, d), "ones", 0, "float32"),
+        ("ln_mlp", (L, d), "ones", 0, "float32"),
+        ("w1", (L, d, F), "normal", d, "bfloat16"),
+        ("w2", (L, F, d), "normal", F, "bfloat16"),
+        ("w3", (L, d, F), "normal", d, "bfloat16"),
+        ("embedding", (V, d), "embed", 0, "bfloat16"),
+        ("lm_head", (d, V), "normal", d, "bfloat16"),
+        ("ln_f", (d,), "ones", 0, "float32")]
+
+
+def test_phi3_reference_weights_and_gaps_are_pinned():
+    """At smoke size on the CPU: each leaf's sum of the seeded weights, and
+    the reference's and the int8 control's gaps on two fixed cases."""
+    import numpy as np
+
+    from bench import reference
+    from bench.smoke_cells import smoke_cell
+
+    cfg, seed = smoke_cell("phi3-code").config, 2**31 + 3
+    w = reference.make_weights(FAMILY, cfg, seed)
+    sums = {k: float(np.asarray(v, np.float64).sum()) for k, v in w.items()}
+    assert sums == pytest.approx({
+        "embedding": -39.37105609464925, "lm_head": -1.0163471805281006,
+        "ln_attn": 128.0, "ln_f": 64.0, "ln_mlp": 128.0,
+        "w1": 2.5414179604695164, "w2": -14.2887108702962,
+        "w3": 28.733529686224983, "wk": -0.3373847334078164,
+        "wo": -0.24251247818028787, "wq": -20.027137755985677,
+        "wv": 5.690725172531529}, rel=1e-5)
+    cases = [([217, 163, 131, 69, 79, 11, 20, 5, 45, 208, 166, 233, 129, 155,
+               248, 187, 162, 139, 143, 239], [71, 209, 172, 1, 101]),
+             ([219, 142, 9, 196, 187, 216, 45], [23, 221, 6])]
+    g = reference.gaps(FAMILY, cfg, seed, cases, control=True)
+    np.testing.assert_allclose(g["served"], [
+        2.21622896194458, 2.5446524620056152, 1.9911556243896484,
+        1.3523038625717163, 3.3788070678710938, 1.8100950717926025,
+        0.648322343826294, 2.1982221603393555], rtol=1e-5)
+    np.testing.assert_array_equal(g["control"], np.zeros(8))
 
 
 def test_roofline_bound_and_peaks():
@@ -346,6 +531,37 @@ def test_trace_reducer_on_a_hand_made_trace():
     gaps = t.idle_gaps(10)
     assert gaps[0] == ["serve_job pump -> jit_decode", pytest.approx(6e-3)]
     assert gaps[1] == ["np.asarray -> jit_decode", pytest.approx(1e-3)]
+
+
+def test_idle_gaps_and_busy_time_cover_every_device():
+    """Four replicas, one a device: the longest gaps are looked for on every
+    device plane, each named by the program that ran next on its own
+    device; busy time stays the mean over the devices."""
+    ms = 1e6
+    events = []
+    for n in range(4):  # device n idles (n + 1) ms between two programs
+        plane = f"/device:TPU:{n}"
+        events += [_ev("XLA Modules", f"jit_decode({n})", 0, 2 * ms, plane),
+                   _ev("XLA Ops", "fusion.1", 0, 2 * ms, plane),
+                   _ev("XLA Modules", f"jit_prefill({n})", (3 + n) * ms,
+                       1 * ms, plane),
+                   _ev("XLA Ops", "fusion.2", (3 + n) * ms, 1 * ms, plane)]
+    t = DeviceTrace(events)
+    assert len(t.devices) == 4
+    assert t.busy_s() == pytest.approx(3e-3)
+    gaps = t.idle_gaps(3)
+    assert [g[1] for g in gaps] == pytest.approx([4e-3, 3e-3, 2e-3])
+    assert all(g[0] == "host idle -> jit_prefill" for g in gaps)
+
+
+def test_replica_balance_is_the_busiest_replica_over_the_mean():
+    read = manifest.reader("replica_balance.p90")
+    ctx = _ctx([], 0.0, 1.0)
+    for took, want in (([10, 10, 10, 10], 1.0), ([12, 10, 9, 9], 1.2),
+                       ([40, 0, 0, 0], 4.0), ([7], None), (None, None),
+                       ([0, 0], None)):
+        ctx.replica_requests = took
+        assert read(ctx) == (pytest.approx(want) if want else None)
 
 
 def test_trace_reducer_on_a_recorded_excerpt():

@@ -58,14 +58,14 @@ PUMP_MS = 0.5 + 3 + 4                           # 7.5 ms
 
 
 def _ctx(events, window_ms=50.0):
-    shape = work.Shape(layers=1, d=8, heads=2, kv_heads=1, head_dim=4,
-                       d_ff=16, vocab=32)
+    cell = manifest.cell("phi3-code")
     outs = [traffic.Outcome(traffic.Request(0, [1, 2], 2), sent=0.0,
                             done=0.04, tokens=[5, 6]),
             traffic.Outcome(traffic.Request(1, [3], 1), sent=0.02,
                             done=0.039, tokens=[7])]
     return Context(loop="closed", outcomes=outs, t0=0.0,
-                   t_end=window_ms / 1e3, setup_s=1.0, shape=shape,
+                   t_end=window_ms / 1e3, setup_s=1.0, cfg=cell.config,
+                   family=cell.family,
                    peak=work.PEAKS["TPU v5 lite"],
                    trace=DeviceTrace(events) if events is not None else None)
 
